@@ -212,4 +212,6 @@ def run_telemetry() -> None:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -360,4 +360,6 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -38,9 +38,7 @@ Usage:
     PYTHONPATH=src python benchmarks/bench_soa_device.py [--smoke]
 
 ``--smoke`` shortens the timed runs for CI; every gate still runs at
-full fleet width (100k / 1M clients). Without jax installed the bench
-reports itself skipped and exits 0 (the device backend is an optional
-extra; ``scalar``/``soa`` never import jax).
+full fleet width (100k / 1M clients).
 """
 import argparse
 import json
@@ -50,19 +48,12 @@ import time
 sys.path.insert(0, "src")
 sys.path.insert(0, "benchmarks")
 
+import jax  # noqa: E402
 from common import emit  # noqa: E402
 
 from repro.storage import Simulation, get_workload  # noqa: E402
+from repro.storage.workloads import STRIPED_MIX  # noqa: E402
 
-try:                     # soft dependency: mirror the backend's gating
-    import jax           # noqa: E402
-except ImportError:      # pragma: no cover - exercised on jax-free hosts
-    jax = None
-
-# striped mix: multi-stream f_* specs plus DL/HPC kernels — exercises
-# kmax > 1 channel layouts, duty cycles, and mixed read/write plans
-STRIPED_CYCLE = ("f_rd_rn_8k", "f_wr_sq_1m", "f_rd_sq_1m", "f_wr_rn_8k",
-                 "dlio_bert", "vpic_io", "dlio_megatron", "s_wr_rn_8k")
 # single-stream mix for the million-client run (same cycle as
 # bench_fleet_scale's 100k smoke, 10x wider)
 WL_CYCLE = ("s_rd_rn_8k", "s_wr_sq_1m", "s_rd_sq_1m", "s_wr_rn_8k")
@@ -91,7 +82,7 @@ def device_step_speedup(n=100_000, steps=6, reps=5, seed=1):
     """Interleaved best-of-``reps`` per-interval wall time of the same
     striped 100k fleet on the host ``soa`` backend vs the fused device
     step, plus an rtol-1e-9 check that the two runs agree."""
-    sims = {b: Simulation(_workloads(STRIPED_CYCLE, n), seed=seed,
+    sims = {b: Simulation(_workloads(STRIPED_MIX, n), seed=seed,
                           backend=b)
             for b in ("soa", "soa-jax")}
     for sim in sims.values():
@@ -134,10 +125,10 @@ def sharded_device_match(n=512, n_shards=4, duration=8.0, seed=2):
     import numpy as np
     from repro.core.runtime import ShardedRuntime
     topo = [i % n_shards for i in range(n)]
-    a = Simulation(_workloads(STRIPED_CYCLE, n), seed=seed,
+    a = Simulation(_workloads(STRIPED_MIX, n), seed=seed,
                    backend="soa-jax", topology=topo)
     a.run(duration)
-    b = Simulation(_workloads(STRIPED_CYCLE, n), seed=seed,
+    b = Simulation(_workloads(STRIPED_MIX, n), seed=seed,
                    backend="soa-jax", topology=topo)
     rt = ShardedRuntime(b, mode="sync", n_shards=n_shards,
                         device_map="auto")
@@ -152,13 +143,6 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="shorter timed runs for CI (same fleet widths)")
     args = ap.parse_args(argv)
-
-    if jax is None:
-        emit("soa_device_skipped", 0.0, "jax not installed")
-        with open("BENCH_soa_device.json", "w") as f:
-            json.dump({"skipped": "jax not installed", "failures": []}, f,
-                      indent=2)
-        return 0
 
     steps = 4 if args.smoke else 6
     reps = 3 if args.smoke else 5
@@ -228,4 +212,6 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -126,4 +126,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -225,7 +225,6 @@ class CaratConfig:
     epsilon: float = 0.1                 # for the ε-greedy baseline
     model: str = "gbdt"                  # svm | fcnn | rnn | tcn | gbdt
     inactive_threshold_s: float = 1.0    # I/O-inactive boundary (>1 s, §III-A)
-    use_pallas_inference: bool = True    # score config space via the Pallas kernel
     # phase re-probing (replayed/dynamic workloads): when the app-level I/O
     # signature shifts (op-mix flip or >reprobe_req_ratio request-size
     # change), reset RPC params to the space default — the trained model's

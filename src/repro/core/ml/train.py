@@ -17,11 +17,12 @@ from repro.core.ml.dataset import TrainingData, collect_training_data
 from repro.core.ml.gbdt import ObliviousGBDT, train_gbdt
 from repro.core.ml.nets import FCNN, TCN, VanillaRNN, train_net
 from repro.core.ml.svm import train_svm
+from repro.utils.compile_cache import CACHE_DIR
 from repro.utils.logging import get_logger
 
 log = get_logger("core.ml.train")
 
-DEFAULT_CACHE = os.environ.get("REPRO_CACHE", "/root/repo/.cache")
+DEFAULT_CACHE = os.environ.get("REPRO_CACHE", str(CACHE_DIR))
 
 
 # ---------------------------------------------------------------- persistence

@@ -4,98 +4,102 @@ CARAT's hot loop scores every candidate configuration against the current
 snapshot every probe interval on every host. The ensemble is tiny (a few
 hundred trees x depth 5) but latency matters (Table VIII) and the batch is
 the whole candidate space, so the kernel keeps the entire model resident in
-VMEM and streams candidate blocks through it:
+VMEM and streams row blocks through it:
 
-* feature gather  -> one-hot matmul on the MXU (no HBM gather);
+* feature gather  -> one one-hot matmul on the MXU against a level-major
+  selector, so each level's split values are a lane-aligned (BN, T) slice;
 * level compares  -> VPU;
-* leaf selection  -> dense (1-b, b) product expansion (branch-free, no
-  gather) contracted against the leaf table.
+* leaf selection  -> a branch-free select tree over the transposed
+  (2**D, T) leaf table: the deepest level picks between sibling leaves,
+  each shallower level between the surviving pairs (2**D - 1 selects);
+* tree sum        -> transpose + sublane reduce into a lane-dense (1, BN)
+  output row.
 
-Grid: one dimension over candidate blocks. Block shapes are padded to the
-TPU tile (8, 128) so the same BlockSpecs are legal on real hardware.
+Every in-kernel op is one Mosaic lowers; ``tests/test_tpu_compile.py``
+compiles the kernel for a described v5e chip at production shapes. The
+gather matmul runs at ``Precision.HIGHEST`` — a one-hot selector times
+an f32 feature is exact only at full f32 precision, and a rounded split
+value flips comparisons near a threshold.
 
-VMEM budget at the default shapes (T<=512 trees, D=5, F<=32, BN=128):
-  x tile     128 x 32 x 4       =  16 KiB
-  sel        32 x (T*D=2560) x 4 = 320 KiB
-  thr        2560 x 4            =  10 KiB
-  leaf       512 x 32 x 4        =  64 KiB
-  expansion  128 x 512 x 32 x 4  =  8 MiB   -> blocked over trees (BT=64)
-The tree-blocked expansion keeps the working set ~1 MiB, comfortably in
-the ~16 MiB VMEM of a v5e core.
+VMEM at the production shapes (T_pad=512, D=5, F_pad=24, BN=128):
+  x tile      128 x 24 x 4       =  12 KiB
+  sel         24 x 2560 x 4      = 240 KiB
+  thr         2560 x 4           =  10 KiB
+  leaf_t      32 x 512 x 4       =  64 KiB
+  split vals  128 x 2560 x 4     = 1.25 MiB
+well inside the scoped VMEM of a v5e core.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _gbdt_kernel(x_ref, sel_ref, thr_ref, leaf_ref, base_ref, out_ref,
-                 *, depth: int, block_trees: int):
-    x = x_ref[...]                       # (BN, F)
-    sel = sel_ref[...]                   # (F, T*D)
-    thr = thr_ref[...]                   # (1, T*D)
-    leaf = leaf_ref[...]                 # (T, 2**D)
-    n_trees = leaf.shape[0]
-    bn = x.shape[0]
-
-    # (1) gather split features for every (tree, level) via MXU matmul
-    g = jnp.dot(x, sel, preferred_element_type=jnp.float32)   # (BN, T*D)
-    bits = (g > thr).astype(jnp.float32)
-    bits = bits.reshape(bn, n_trees, depth)
-
-    # (2) expand level bits into one-hot leaf indicators, tree-blocked to
-    # bound the VMEM working set, and contract with the leaf table
-    acc = jnp.zeros((bn,), dtype=jnp.float32)
-    n_blocks = n_trees // block_trees
-    for tb in range(n_blocks):            # static unroll (n_trees is static)
-        s = tb * block_trees
-        b_blk = jax.lax.slice_in_dim(bits, s, s + block_trees, axis=1)
-        leaf_blk = jax.lax.slice_in_dim(leaf, s, s + block_trees, axis=0)
-        # deepest level first: the concat expansion builds the leaf index
-        # MSB-last, and level 0 is the MSB (see ref.py)
-        p = jnp.ones((bn, block_trees, 1), dtype=jnp.float32)
-        for level in reversed(range(depth)):
-            b = jax.lax.slice_in_dim(b_blk, level, level + 1, axis=2)
-            p = jnp.concatenate([p * (1.0 - b), p * b], axis=-1)
-        acc = acc + jnp.einsum("ntj,tj->n", p, leaf_blk)
-
-    out_ref[...] = base_ref[0, 0] + acc
+def default_interpret() -> bool:
+    """Whether Pallas kernels run interpreted: on the CPU backend (where
+    the interpreter is Pallas's only lowering), never on a TPU."""
+    return jax.default_backend() == "cpu"
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("depth", "block_n", "block_trees", "interpret"))
+def _gbdt_kernel(x_ref, sel_ref, thr_ref, leaf_t_ref, out_ref, *,
+                 depth: int, t_pad: int):
+    # (BN, D*T_pad): split value of every (level, tree), level-major
+    g = jnp.dot(x_ref[...], sel_ref[...],
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST)
+    bits = g > thr_ref[...]
+    leaf_t = leaf_t_ref[...]                       # (2**D, T_pad)
+    # leaf index bit-packs the levels with level 0 as the MSB (ref.py),
+    # so the deepest level chooses between adjacent leaves
+    vals = [leaf_t[j:j + 1, :] for j in range(1 << depth)]
+    for level in reversed(range(depth)):
+        bit = bits[:, level * t_pad:(level + 1) * t_pad]
+        vals = [jnp.where(bit, vals[2 * j + 1], vals[2 * j])
+                for j in range(len(vals) // 2)]
+    # (BN, T_pad) per-tree leaf values -> lane-dense (1, BN) row sums
+    out_ref[...] = jnp.sum(vals[0].T, axis=0, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def gbdt_logits_pallas(
-    x: jnp.ndarray,       # (N, F) float32, N % block_n == 0, F padded
-    sel: jnp.ndarray,     # (F, T*D) float32
-    thr: jnp.ndarray,     # (1, T*D) float32
-    leaf: jnp.ndarray,    # (T, 2**D) float32, T % block_trees == 0
-    base: jnp.ndarray,    # (1, 1) float32
+    x: jnp.ndarray,       # (N, F_pad) float32, N % block_n == 0
+    sel: jnp.ndarray,     # (F_pad, D*T_pad) float32 one-hot, level-major
+    thr: jnp.ndarray,     # (1, D*T_pad) float32, level-major
+    leaf_t: jnp.ndarray,  # (2**D, T_pad) float32, T_pad % 128 == 0
+    base: jnp.ndarray,    # () float32
     *,
-    depth: int,
     block_n: int = 128,
-    block_trees: int = 64,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
+    """(N,) logits. ``interpret=None`` chooses from the platform
+    (:func:`default_interpret`)."""
+    if interpret is None:
+        interpret = default_interpret()
     n, f = x.shape
-    td = sel.shape[1]
-    t = leaf.shape[0]
-    assert n % block_n == 0 and t % block_trees == 0
-    grid = (n // block_n,)
-    return pl.pallas_call(
-        functools.partial(_gbdt_kernel, depth=depth, block_trees=block_trees),
-        grid=grid,
+    n_leaves, t_pad = leaf_t.shape
+    depth = n_leaves.bit_length() - 1
+    assert n % block_n == 0 and t_pad % 128 == 0
+    assert sel.shape == (f, depth * t_pad) and thr.shape == (1, depth * t_pad)
+    # int32 block indices: under jax_enable_x64 a bare 0 is an i64 that
+    # Mosaic cannot return from an index map
+    def resident(i):
+        return jnp.int32(0), jnp.int32(0)
+
+    out = pl.pallas_call(
+        functools.partial(_gbdt_kernel, depth=depth, t_pad=t_pad),
+        grid=(n // block_n,),
         in_specs=[
-            pl.BlockSpec((block_n, f), lambda i: (i, 0)),      # x: stream
-            pl.BlockSpec((f, td), lambda i: (0, 0)),           # sel: resident
-            pl.BlockSpec((1, td), lambda i: (0, 0)),           # thr: resident
-            pl.BlockSpec((t, leaf.shape[1]), lambda i: (0, 0)),  # leaf
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),            # base
+            pl.BlockSpec((block_n, f), lambda i: (i, jnp.int32(0))),
+            pl.BlockSpec(sel.shape, resident),
+            pl.BlockSpec(thr.shape, resident),
+            pl.BlockSpec(leaf_t.shape, resident),
         ],
-        out_specs=pl.BlockSpec((block_n,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
+        out_specs=pl.BlockSpec((1, block_n), lambda i: (jnp.int32(0), i)),
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         interpret=interpret,
-    )(x, sel, thr, leaf, base)
+    )(x, sel, thr, leaf_t)
+    return base + out[0]

@@ -1,12 +1,18 @@
 """Public op: batched GBDT probability scoring with backend switch.
 
 ``pack_gbdt`` converts a trained :class:`ObliviousGBDT` into the padded,
-TPU-tile-aligned tensors both backends consume. ``gbdt_predict_proba``
-scores a candidate batch; backend "pallas" runs the kernel (interpret mode
-on CPU), backend "jnp" runs the oracle, backend "numpy" uses the model's
-native numpy path (fastest on this CPU container — used by the online
-controller loop), and backend "auto" picks per call from the accelerator
-platform and the batch size.
+TPU-tile-aligned tensors the Pallas kernel consumes. ``gbdt_predict_proba``
+scores a candidate batch; backend "pallas" runs the kernel (compiled on a
+TPU, interpreted on the CPU — :func:`default_interpret` decides), backend
+"jnp" runs the oracle, backend "numpy" uses the model's native numpy path
+(fastest on a CPU host — used by the online controller loop there), and
+backend "auto" picks per call from the accelerator platform and the batch
+size.
+
+The packed tensors stay NumPy and move to the device only inside a call,
+so building or pickling a scorer (and the policy that holds it) touches
+no device — a worker process that unpickles a policy never reaches for a
+chip.
 
 :class:`GridGBDTScorer` is the fleet-tuning entry point: it scores a
 whole node's clients against the static candidate grid in one call,
@@ -15,6 +21,7 @@ batch size (see the class docstring).
 """
 from __future__ import annotations
 
+from collections import Counter
 from dataclasses import dataclass
 from typing import Literal, Optional
 
@@ -54,52 +61,39 @@ def _round_up(v: int, m: int) -> int:
 
 @dataclass(frozen=True)
 class PackedGBDT:
-    sel: jnp.ndarray      # (F_pad, T_pad * D) one-hot feature selector
-    thr: jnp.ndarray      # (1, T_pad * D)
-    leaf: jnp.ndarray     # (T_pad, 2**D)
-    base: jnp.ndarray     # (1, 1)
-    depth: int
-    n_features: int       # unpadded
-    n_trees: int          # unpadded
-    f_pad: int
-    block_trees: int = 64
-    model: Optional[ObliviousGBDT] = None   # source model (backend "numpy")
+    """Kernel tensors (NumPy; see the module docstring)."""
+    sel: np.ndarray       # (F_pad, D * T_pad) one-hot selector, level-major
+    thr: np.ndarray       # (1, D * T_pad) thresholds, level-major
+    leaf_t: np.ndarray    # (2**D, T_pad) transposed leaf table
+    base: np.float32
+    model: ObliviousGBDT  # source model (backends "numpy" and "jnp")
+
+    @property
+    def f_pad(self) -> int:
+        return self.sel.shape[0]
 
     @property
     def t_pad(self) -> int:
-        return self.leaf.shape[0]
+        return self.leaf_t.shape[1]
 
 
-def pack_gbdt(model: ObliviousGBDT, block_trees: int = 64,
-              lane: int = 128) -> PackedGBDT:
+def pack_gbdt(model: ObliviousGBDT) -> PackedGBDT:
     feat, thr, leaf, base = model.packed()
     t, d = feat.shape
-    f = model.n_features
-    t_pad = _round_up(max(t, 1), block_trees)
-    f_pad = _round_up(f, 8)
-    # padded trees: all-false splits (threshold +inf) and zero leaves
-    feat_p = np.zeros((t_pad, d), dtype=np.int64)
-    feat_p[:t] = feat
-    thr_p = np.full((t_pad, d), np.float32(np.inf))
-    thr_p[:t] = thr
-    leaf_p = np.zeros((t_pad, leaf.shape[1]), dtype=np.float32)
-    leaf_p[:t] = leaf
-    # one-hot selector (F_pad, T_pad*D), level-major per tree
-    sel = np.zeros((f_pad, t_pad * d), dtype=np.float32)
-    cols = np.arange(t_pad * d)
-    sel[feat_p.reshape(-1), cols] = 1.0
-    return PackedGBDT(
-        sel=jnp.asarray(sel),
-        thr=jnp.asarray(thr_p.reshape(1, -1)),
-        leaf=jnp.asarray(leaf_p),
-        base=jnp.asarray(base.reshape(1, 1)),
-        depth=d,
-        n_features=f,
-        n_trees=t,
-        f_pad=f_pad,
-        block_trees=block_trees,
-        model=model,
-    )
+    t_pad = _round_up(max(t, 1), 128)          # trees fill whole lane tiles
+    f_pad = _round_up(model.n_features, 8)
+    # padded trees: all-false splits (threshold +inf) and zero leaves;
+    # column l * t_pad + k holds level l of tree k, so each level is one
+    # lane-aligned (BN, t_pad) slice of the kernel's split values
+    thr_p = np.full((d, t_pad), np.float32(np.inf))
+    thr_p[:, :t] = thr.T
+    sel = np.zeros((f_pad, d, t_pad), dtype=np.float32)
+    sel[feat.T, np.arange(d)[:, None], np.arange(t)[None, :]] = 1.0
+    leaf_t = np.zeros((leaf.shape[1], t_pad), dtype=np.float32)
+    leaf_t[:, :t] = leaf.T
+    return PackedGBDT(sel=sel.reshape(f_pad, d * t_pad),
+                      thr=thr_p.reshape(1, d * t_pad), leaf_t=leaf_t,
+                      base=np.float32(base[0]), model=model)
 
 
 def gbdt_predict_proba(
@@ -107,56 +101,48 @@ def gbdt_predict_proba(
     X: np.ndarray,
     backend: Backend = "pallas",
     block_n: int = 128,
-    interpret: bool = True,
 ) -> np.ndarray:
     X = np.asarray(X, dtype=np.float32)
     n, f = X.shape
-    if f != packed.n_features:
-        raise ValueError(f"feature dim {f} != model {packed.n_features}")
+    m = packed.model
+    if f != m.n_features:
+        raise ValueError(f"feature dim {f} != model {m.n_features}")
     backend = resolve_backend(backend, n)
     if backend == "numpy":
-        if packed.model is None:
-            raise ValueError("backend 'numpy' needs a PackedGBDT built by "
-                             "pack_gbdt from a live ObliviousGBDT")
-        return packed.model.predict_proba(X)
-    n_pad = _round_up(max(n, 1), block_n)
-    Xp = np.zeros((n_pad, packed.f_pad), dtype=np.float32)
-    Xp[:n, :f] = X
-    x = jnp.asarray(Xp)
+        return m.predict_proba(X)
     if backend == "pallas":
+        n_pad = _round_up(max(n, 1), block_n)
+        Xp = np.zeros((n_pad, packed.f_pad), dtype=np.float32)
+        Xp[:n, :f] = X
         logits = gbdt_logits_pallas(
-            x, packed.sel, packed.thr, packed.leaf, packed.base,
-            depth=packed.depth, block_n=block_n,
-            block_trees=packed.block_trees, interpret=interpret)
+            jnp.asarray(Xp), packed.sel, packed.thr, packed.leaf_t,
+            packed.base, block_n=block_n)
     elif backend == "jnp":
-        logits = gbdt_logits_ref(x, packed.sel, packed.thr[0], packed.leaf,
-                                 packed.base[0])
+        feat, thr, leaf, base = m.packed()
+        logits = gbdt_logits_ref(jnp.asarray(X), feat, thr, leaf, base[0])
     else:
         raise ValueError(f"unknown backend {backend!r}")
-    probs = jax.nn.sigmoid(logits)
-    return np.asarray(probs[:n])
+    return np.asarray(jax.nn.sigmoid(logits)[:n])
 
 
 class PallasGBDTScorer:
     """predict_proba adapter: CARAT controller -> Pallas GBDT kernel.
 
     On TPU this is the deployed inference path (whole candidate space in one
-    kernel launch per probe); on CPU it runs in interpret mode, so the
+    kernel launch per probe); on CPU the kernel runs interpreted, so the
     online benchmarks default to the model's native numpy path and the
     kernel is exercised by the correctness suite instead.
     """
 
     def __init__(self, model: ObliviousGBDT, backend: Backend = "pallas",
-                 block_n: int = 128, interpret: bool = True):
+                 block_n: int = 128):
         self.packed = pack_gbdt(model)
         self.backend = backend
         self.block_n = block_n
-        self.interpret = interpret
 
     def predict_proba(self, X: np.ndarray) -> np.ndarray:
         return gbdt_predict_proba(self.packed, X, backend=self.backend,
-                                  block_n=self.block_n,
-                                  interpret=self.interpret)
+                                  block_n=self.block_n)
 
 
 class GridGBDTScorer:
@@ -182,21 +168,22 @@ class GridGBDTScorer:
     That is what lets the fleet controller prove its decisions equal the
     per-client path. Backends "jnp"/"pallas" go through the packed kernel
     tensors (float32-tolerance agreement, used on accelerators).
+
+    ``calls`` counts scored batches by ``(resolved backend, kernel rows)``
+    — what "auto" chose for each batch size.
     """
 
     def __init__(self, model: ObliviousGBDT, theta: np.ndarray,
                  backend: Backend = "auto", block_n: int = 128,
-                 interpret: Optional[bool] = None, cand_chunk: int = 8):
+                 cand_chunk: int = 8):
         self.model = model
         self.theta = np.asarray(theta, dtype=np.float32)
         if self.theta.ndim != 2:
             raise ValueError("theta must be (n_candidates, n_theta_features)")
         self.backend = backend
         self.block_n = block_n
-        # None -> compile on TPU hosts, interpret elsewhere (CPU Pallas only
-        # runs in interpret mode)
-        self.interpret = interpret
         self.cand_chunk = max(int(cand_chunk), 1)
+        self.calls: Counter = Counter()
         self._buffers: dict = {}       # (n, chunk) -> (int32 idx, f32 gather)
         self.packed = pack_gbdt(model)
         n_h = model.n_features - self.theta.shape[1]
@@ -235,8 +222,9 @@ class GridGBDTScorer:
             H = H[None, :]
         if H.shape[1] != self.n_h:
             raise ValueError(f"client feature dim {H.shape[1]} != {self.n_h}")
-        be = resolve_backend(backend or self.backend,
-                             H.shape[0] * self.n_candidates)
+        rows = H.shape[0] * self.n_candidates
+        be = resolve_backend(backend or self.backend, rows)
+        self.calls[(be, rows)] += 1
         if be == "numpy":
             return self._predict_numpy(H)
         return self._predict_packed(H, be)
@@ -276,9 +264,7 @@ class GridGBDTScorer:
         n, c = H.shape[0], self.n_candidates
         X = np.concatenate([np.repeat(H, c, axis=0),
                             np.tile(self.theta, (n, 1))], axis=1)
-        interpret = (self.interpret if self.interpret is not None
-                     else jax.default_backend() != "tpu")
         probs = gbdt_predict_proba(self.packed, X, backend=backend,
-                                   block_n=self.block_n,
-                                   interpret=interpret)
+                                   block_n=self.block_n)
         return np.asarray(probs, dtype=np.float64).reshape(n, c)
+

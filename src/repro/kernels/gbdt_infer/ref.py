@@ -1,9 +1,10 @@
 """Pure-jnp oracle for oblivious-GBDT ensemble inference.
 
-The math (shared with the Pallas kernel): gather split features with a
-one-hot matmul, compare against thresholds, expand the level bits into a
-one-hot leaf indicator by repeated (1-b, b) concatenation, and contract
-with the leaf table. Fully dense — no gathers — by construction.
+Straight from the model's own layout, independent of the kernel's
+packing: gather each (tree, level) split feature, compare against its
+threshold, bit-pack the levels into a leaf index (level 0 is the MSB, as
+in ``ObliviousGBDT.decision_function``), gather the leaf values and sum
+over trees. The gather is an integer gather, exact on every backend.
 """
 from __future__ import annotations
 
@@ -12,26 +13,14 @@ import jax.numpy as jnp
 
 def gbdt_logits_ref(
     x: jnp.ndarray,       # (N, F) float32
-    sel: jnp.ndarray,     # (F, T*D) float32 one-hot feature selector
-    thr: jnp.ndarray,     # (T*D,) float32 thresholds (level-major per tree)
+    feat: jnp.ndarray,    # (T, D) int split feature per tree level
+    thr: jnp.ndarray,     # (T, D) float32 split thresholds
     leaf: jnp.ndarray,    # (T, 2**D) float32 leaf values
-    base: jnp.ndarray,    # (1,) float32
+    base,                 # scalar initial log-odds
 ) -> jnp.ndarray:         # (N,)
-    n = x.shape[0]
-    t, n_leaves = leaf.shape
-    d = (n_leaves - 1).bit_length()
-    g = x @ sel                                       # (N, T*D) gathered
-    bits = (g > thr[None, :]).astype(x.dtype).reshape(n, t, d)
-    # the concat expansion makes the LAST-processed level the MSB of the
-    # leaf index; numpy's decision_function treats level 0 as the MSB, so
-    # process levels deepest-first
-    p = jnp.ones((n, t, 1), dtype=x.dtype)
-    for level in reversed(range(d)):
-        b = bits[:, :, level:level + 1]
-        p = jnp.concatenate([p * (1.0 - b), p * b], axis=-1)
-    contrib = jnp.einsum("ntj,tj->n", p, leaf)
-    return base[0] + contrib
-
-
-def gbdt_proba_ref(x, sel, thr, leaf, base) -> jnp.ndarray:
-    return 1.0 / (1.0 + jnp.exp(-gbdt_logits_ref(x, sel, thr, leaf, base)))
+    d = feat.shape[1]
+    bits = (x[:, feat] > thr[None]).astype(jnp.int32)        # (N, T, D)
+    weights = jnp.left_shift(1, jnp.arange(d - 1, -1, -1, dtype=jnp.int32))
+    idx = (bits * weights).sum(axis=2)                       # (N, T)
+    contrib = jnp.take_along_axis(leaf[None], idx[..., None], axis=2)[..., 0]
+    return base + contrib.sum(axis=1)

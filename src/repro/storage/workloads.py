@@ -12,7 +12,7 @@ throughput given the client's current tunables and cluster state.
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
-from typing import Dict, Tuple
+from typing import Dict, List, Tuple
 
 from repro.utils.registry import Registry
 
@@ -155,6 +155,20 @@ _reg(WorkloadSpec(
     n_streams=2,
     file_bytes=8 << 30,
 ))
+
+
+# The striped fleet mix: five-stream filebench specs (each client striped
+# over several OSTs, the normal parallel-file-system client shape) plus
+# DL/HPC kernels — multi-channel layouts, duty cycles and mixed read/write
+# plans in one fleet.
+STRIPED_MIX = ("f_rd_rn_8k", "f_wr_sq_1m", "f_rd_sq_1m", "f_wr_rn_8k",
+               "dlio_bert", "vpic_io", "dlio_megatron", "s_wr_rn_8k")
+
+
+def striped_fleet(n: int) -> List[WorkloadSpec]:
+    """Workloads of an ``n``-client fleet cycling through STRIPED_MIX."""
+    return [get_workload(STRIPED_MIX[i % len(STRIPED_MIX)])
+            for i in range(n)]
 
 
 def filebench_names(streams: str = "s") -> Tuple[str, ...]:

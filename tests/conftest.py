@@ -1,23 +1,13 @@
 import os
 import sys
 
+import pytest
+
 # tests run against a single CPU device; the 512-device dry-run is
 # exercised via subprocess (test_dryrun_mechanism) so it never leaks
 # XLA_FLAGS into this process.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-
-# Prefer real hypothesis when installed; otherwise run the property tests
-# through the bounded in-repo shim so the suite still collects on minimal
-# containers (requirements.txt lists the real dependency).
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    from repro.testing import hypothesis_fallback
-
-    hypothesis_fallback.install()
-
-import pytest  # noqa: E402
 
 
 @pytest.fixture(scope="session")

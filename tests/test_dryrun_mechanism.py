@@ -22,7 +22,7 @@ SCRIPT = textwrap.dedent("""
     import json
     import jax
     import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
     from repro.config import get_arch, reduced_config
     from repro.config.types import ParallelConfig, RunConfig, ShapeConfig
@@ -37,7 +37,8 @@ SCRIPT = textwrap.dedent("""
     from repro.train.step import make_train_step
 
     assert jax.device_count() == 8
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     cfg = reduced_config(get_arch("granite-3-2b"))
     shape = ShapeConfig("tiny", 64, 8, "train")
     par = ParallelConfig(fsdp=True, remat="dots")

@@ -312,3 +312,41 @@ def test_spaces_error_names_offending_grid():
         CaratSpaces((16,), (8,), ())
     with pytest.raises(ValueError, match=r"rpc_window_pages.*\(16, 16\)"):
         CaratSpaces((16, 16), (8,), (64,))
+
+
+# ------------------------------------------------------- device-free pickle
+def test_pickled_carat_policy_holds_no_device_arrays():
+    """A policy (its GBDT scorers' packed kernel tensors included) stays
+    NumPy after scoring on the jnp path, so a worker process that
+    unpickles it never reaches for a device."""
+    import io
+    import pickle
+
+    import jax
+
+    from repro.core.ml.gbdt import ObliviousGBDT
+
+    rng = np.random.default_rng(0)
+    n_features = 22                     # 20 snapshot + 2 candidate features
+    model = ObliviousGBDT(
+        feat=rng.integers(0, n_features, (40, 5)).astype(np.int32),
+        thr=rng.normal(size=(40, 5)).astype(np.float32),
+        leaf=rng.normal(size=(40, 32)).astype(np.float32),
+        base=0.0, n_features=n_features)
+    sim = _sim(n=4)            # ProcessRuntime pickles scalar fleets
+    policy = sim.attach_policy(CaratPolicy(
+        SPACES, {"read": model, "write": model}, backend="jnp"))
+    sim.run(3.0)
+    assert any(be == "jnp" for grid in policy.tuner.grid_models.values()
+               for be, _ in grid.calls)
+
+    found = []
+
+    class _Finder(pickle.Pickler):
+        def reducer_override(self, obj):
+            if isinstance(obj, jax.Array):
+                found.append(type(obj).__name__)
+            return NotImplemented
+
+    _Finder(io.BytesIO()).dump(policy)
+    assert found == []

@@ -238,11 +238,10 @@ def test_storage_layer_runs_without_jax():
         import sys
 
         class _BlockJax:
-            def find_module(self, name, path=None):
+            def find_spec(self, name, path=None, target=None):
                 if name == "jax" or name.startswith("jax."):
-                    return self
-            def load_module(self, name):
-                raise ImportError(f"import of {name!r} blocked for test")
+                    raise ImportError(f"import of {name!r} blocked for test")
+                return None
 
         sys.meta_path.insert(0, _BlockJax())
         for mod in list(sys.modules):
