@@ -33,8 +33,8 @@ def _split_sequence(X: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
 def _dense_init(rng, n_in, n_out):
     k1, _ = jax.random.split(rng)
     scale = jnp.sqrt(2.0 / n_in)
-    return {"w": jax.random.normal(k1, (n_in, n_out)) * scale,
-            "b": jnp.zeros((n_out,))}
+    return {"w": jax.random.normal(k1, (n_in, n_out), jnp.float32) * scale,
+            "b": jnp.zeros((n_out,), jnp.float32)}
 
 
 def _dense(p, x):
@@ -107,7 +107,7 @@ class VanillaRNN:
     def apply(self, params, X):
         seq, static = _split_sequence(X)
         n = X.shape[0]
-        h = jnp.zeros((n, self.hidden))
+        h = jnp.zeros((n, self.hidden), jnp.float32)
 
         def cell(h, x_t):
             h2 = jnp.tanh(_dense(params["wx"], x_t) + _dense(params["wh"], h))
@@ -135,12 +135,14 @@ class TCN:
         k1, k2, k3 = jax.random.split(rng, 3)
         c = self.channels
         return {
-            "conv1": {"w": jax.random.normal(k1, (self.kernel, self.step_dim, c))
+            "conv1": {"w": jax.random.normal(
+                k1, (self.kernel, self.step_dim, c), jnp.float32)
                       * jnp.sqrt(2.0 / (self.kernel * self.step_dim)),
-                      "b": jnp.zeros((c,))},
-            "conv2": {"w": jax.random.normal(k2, (self.kernel, c, c))
+                      "b": jnp.zeros((c,), jnp.float32)},
+            "conv2": {"w": jax.random.normal(
+                k2, (self.kernel, c, c), jnp.float32)
                       * jnp.sqrt(2.0 / (self.kernel * c)),
-                      "b": jnp.zeros((c,))},
+                      "b": jnp.zeros((c,), jnp.float32)},
             "out": _dense_init(k3, c, 1),
         }
 
@@ -207,8 +209,12 @@ def train_net(
         m = jax.tree_util.tree_map(lambda m, g: 0.9 * m + 0.1 * g, opt["m"], g)
         v = jax.tree_util.tree_map(lambda v, g: 0.999 * v + 0.001 * g * g,
                                    opt["v"], g)
-        mh = jax.tree_util.tree_map(lambda m: m / (1 - 0.9 ** t), m)
-        vh = jax.tree_util.tree_map(lambda v: v / (1 - 0.999 ** t), v)
+        # float32 bias corrections: a Python float to a traced int32 power
+        # is float64 under x64, which would promote the parameters
+        b1 = 1 - jnp.float32(0.9) ** t
+        b2 = 1 - jnp.float32(0.999) ** t
+        mh = jax.tree_util.tree_map(lambda m: m / b1, m)
+        vh = jax.tree_util.tree_map(lambda v: v / b2, v)
         p2 = jax.tree_util.tree_map(
             lambda p, mh, vh: p - lr * (mh / (jnp.sqrt(vh) + 1e-8)
                                         + weight_decay * p), p, mh, vh)

@@ -378,7 +378,13 @@ class CaratPolicy(TuningPolicy):
     def finish_step(self, t: float) -> None:
         """Arbitrate every node with a pending stage-2 boundary: one
         vectorized Algorithm 2 call across all of them (or the per-node
-        scalar loop in ``stage2="scalar"`` mode)."""
+        scalar loop in ``stage2="scalar"`` mode). The ``policy.stage2``
+        span covers the scan for pending nodes too, so it is recorded at
+        every step."""
+        with _telemetry().span("policy.stage2", cat="policy"):
+            self._finish_step()
+
+    def _finish_step(self) -> None:
         arbs = self._pending_arbiters()
         if not arbs:
             return

@@ -34,6 +34,7 @@ import numpy as np
 
 from repro.core.policy import CaratSpaces
 from repro.core.runtime.telemetry.clock import perf_s
+from repro.core.runtime.telemetry.recorder import active as _telemetry
 from repro.utils.rng import RngStream
 
 # A scorer maps a batch of rows (n_candidates, n_features) -> probabilities.
@@ -133,6 +134,7 @@ class _TunerBase:
         feats = np.asarray(feats, dtype=np.float32)
         if feats.shape[0] != n:
             raise ValueError(f"{n} ops but {feats.shape[0]} feature rows")
+        rec = _telemetry()
         t0 = perf_s()
         probs = np.empty((n, len(self._cands)), dtype=np.float64)
         t_inf = 0.0
@@ -141,10 +143,12 @@ class _TunerBase:
                 raise KeyError(op)         # mirror the scalar path
             rows = [i for i, o in enumerate(ops) if o == op]
             t1 = perf_s()
-            probs[rows] = self._probs_many(op, feats[rows])
+            with rec.span("carat.score", cat="policy"):
+                probs[rows] = self._probs_many(op, feats[rows])
             t_inf += perf_s() - t1
         self.inference_time_total += t_inf
-        chosen = self._select_many(ops, probs, rngs)
+        with rec.span("carat.select", cat="policy"):
+            chosen = self._select_many(ops, probs, rngs)
         self.tune_time_total += perf_s() - t0
         self.tune_count += n
         return [self._cands[int(k)] if k >= 0 else None for k in chosen]

@@ -15,7 +15,11 @@ Cost model, because instrumentation sits on real hot paths:
   per-message work with ``if rec.enabled:``.
 * **spans** push one event into the ring at exit (two clock reads, one
   slot write under the lock — the ring is shared with broker/heartbeat
-  threads).
+  threads). When jax is already imported in the process, each span also
+  opens a ``jax.profiler.TraceAnnotation`` of the same name, so a
+  profiler trace shows the program's spans on the host plane, on the
+  device planes' clock. jax is looked up in ``sys.modules``, never
+  imported: the package still imports without it.
 * **counters/gauges/hists** are dict accumulations only; dirty counters
   are flushed into the ring as :class:`CounterEvent` samples once per
   interval (``set_interval``), not per increment, so a 100k-client
@@ -27,6 +31,7 @@ recorder wants; totals in :meth:`snapshot` stay exact regardless.
 """
 from __future__ import annotations
 
+import sys
 import threading
 from contextlib import contextmanager
 from typing import Dict, Iterator, Optional, Union
@@ -37,25 +42,40 @@ from repro.core.runtime.telemetry.events import (CounterEvent, EventBatch,
 
 
 class _Span:
-    """Reusable-shape span context manager; one allocation per span."""
+    """Reusable-shape span context manager; one allocation per span (two
+    with the profiler annotation)."""
 
-    __slots__ = ("_rec", "_name", "_cat", "_t0")
+    __slots__ = ("_rec", "_name", "_cat", "_t0", "_ann")
 
     def __init__(self, rec: "Recorder", name: str, cat: str):
         self._rec = rec
         self._name = name
         self._cat = cat
         self._t0 = 0.0
+        ann = rec._annotation
+        self._ann = ann(name) if ann is not None else None
 
     def __enter__(self) -> "_Span":
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = self._rec.clock.now()
         return self
 
     def __exit__(self, *exc) -> None:
         rec = self._rec
         t1 = rec.clock.now()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         rec._push(SpanEvent(name=self._name, cat=self._cat, t0=self._t0,
                             dur=t1 - self._t0, interval=rec.interval))
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` if jax is already imported, else
+    None (looked up, never imported)."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    return getattr(profiler, "TraceAnnotation", None)
 
 
 class _NullSpan:
@@ -113,6 +133,7 @@ class Recorder:
         self.capacity = int(capacity)
         self.clock = clock or Clock()
         self.interval = -1
+        self._annotation = _profiler_annotation()
         self._lock = threading.Lock()
         self._ring = [None] * self.capacity      # preallocated slots
         self._head = 0                           # next write index

@@ -39,6 +39,13 @@ shapes (cache hit, no retrace), while a channel-layout change alters
 input shapes and retraces exactly once. ``DeviceFleet.n_traces``
 counts retraces for the jit-stability tests.
 
+Telemetry: each host-side part of a step runs in a span of the
+``fleet.`` vocabulary (``fleet.step``, ``fleet.push``, ``fleet.statics``,
+``fleet.mask``, ``fleet.noise``, ``fleet.sync_host``), and an enabled
+recorder counts the bytes every host<->device transfer moves
+(``fleet.h2d_bytes``, ``fleet.d2h_bytes``). Device-to-device copies
+between shards are not counted.
+
 Ownership: whichever fleet last stepped owns the truth. Host-side
 reads go through :meth:`SoACore.ensure_host` (lazy pull); host-side
 state writes mark the device copy stale and the next device step
@@ -57,6 +64,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro.core.runtime.telemetry.recorder import active as _telemetry
 from repro.storage.params import PAGE_SIZE, PFSParams
 from repro.storage.pfs import PFSCluster
 from repro.storage.soa import OP_FIELDS, SoACore, resolve_xp
@@ -78,6 +86,18 @@ STATIC_FIELDS = (
 
 OST_STATE_FIELDS = ("ost_wait", "ost_util", "ost_inflight",
                     "ost_served_bytes", "ost_served_rpcs")
+
+
+def _nbytes(tree) -> int:
+    """Bytes held by the arrays of a pytree (what a transfer moves)."""
+    return sum(int(x.nbytes) for x in jax.tree.leaves(tree))
+
+
+def _statics_current(core: SoACore, seen: int) -> bool:
+    """Whether statics version ``seen`` is the core's current one, so
+    ``_ensure_static`` would recompute nothing and nothing is uploaded."""
+    return (core._layout_ok and core._static_ok
+            and seen == core._static_version)
 
 
 def _onehot_T(n_osts: int, ch_ost) -> np.ndarray:
@@ -478,39 +498,53 @@ class DeviceFleet:
     def _push(self) -> None:
         """Upload host state to the device (host stays valid until the
         next fused step marks it stale)."""
-        self._state = jax.device_put(self._host_state(), self.device)
+        rec = _telemetry()
+        with rec.span("fleet.push", cat="fleet"):
+            self._state = jax.device_put(self._host_state(), self.device)
+            if rec.enabled:
+                rec.count("fleet.h2d_bytes", _nbytes(self._state))
         self.device_stale = False
         self._mask = None            # dirty may have changed: recompute
 
     def _refresh_statics(self) -> None:
         core = self.core
-        core._ensure_static()
-        if self._static_seen != core._static_version:
+        if _statics_current(core, self._static_seen):
+            return
+        rec = _telemetry()
+        with rec.span("fleet.statics", cat="fleet"):
+            core._ensure_static()
             st = core._static
             d = {f: np.asarray(getattr(st, f)) for f in STATIC_FIELDS}
             d["onehot_T"] = _onehot_T(core.p.n_osts, st.ch_ost)
             self._statics = jax.device_put(d, self.device)
             self._static_seen = core._static_version
+            if rec.enabled:
+                rec.count("fleet.h2d_bytes", _nbytes(self._statics))
 
     def sync_host(self) -> None:
         """Pull device state back into the core/cluster host arrays.
         The device copy remains authoritative (reads don't invalidate)."""
-        h = jax.tree.map(np.asarray, self._state)
-        core, cl = self.core, self.cluster
-        core.dirty_bytes[:] = h["dirty"]
-        core.last_drain[:] = h["last_drain"]
-        # full-fleet contract: every client's waits row is the OST vector
-        core.waits[:, :] = h["ost_wait"][None, :]
-        for f in OP_FIELDS:
-            getattr(core.read, f)[:] = h["read"][f]
-            getattr(core.write, f)[:] = h["write"][f]
-        core.dirty_peak_bytes[:] = h["dirty_peak"]
-        core.inflight_peak[:] = h["inflight_peak"]
-        cl.wait_s[:] = h["ost_wait"]
-        cl.utilization[:] = h["ost_util"]
-        cl.inflight[:] = h["ost_inflight"]
-        cl.served_bytes[:] = h["ost_served_bytes"]
-        cl.served_rpcs[:] = h["ost_served_rpcs"]
+        rec = _telemetry()
+        with rec.span("fleet.sync_host", cat="fleet"):
+            h = jax.tree.map(np.asarray, self._state)
+            core, cl = self.core, self.cluster
+            core.dirty_bytes[:] = h["dirty"]
+            core.last_drain[:] = h["last_drain"]
+            # full-fleet contract: every client's waits row is the OST
+            # vector
+            core.waits[:, :] = h["ost_wait"][None, :]
+            for f in OP_FIELDS:
+                getattr(core.read, f)[:] = h["read"][f]
+                getattr(core.write, f)[:] = h["write"][f]
+            core.dirty_peak_bytes[:] = h["dirty_peak"]
+            core.inflight_peak[:] = h["inflight_peak"]
+            cl.wait_s[:] = h["ost_wait"]
+            cl.utilization[:] = h["ost_util"]
+            cl.inflight[:] = h["ost_inflight"]
+            cl.served_bytes[:] = h["ost_served_bytes"]
+            cl.served_rpcs[:] = h["ost_served_rpcs"]
+            if rec.enabled:
+                rec.count("fleet.d2h_bytes", _nbytes(h))
         self.host_stale = False
 
     def _take_ownership(self) -> None:
@@ -532,25 +566,37 @@ class DeviceFleet:
         """Advance the fleet one interval on-device; returns the
         per-client cumulative read+write app_bytes as a *device* array
         (callers pull it only if they need the throughput series)."""
-        core = self.core
-        self._take_ownership()
-        if self.device_stale or self._state is None:
-            self._push()
-        self._refresh_statics()
-        if self._mask is None or self._wl_seen != core._wl_version:
-            # no valid predicted mask (fresh push or workload mutation):
-            # recompute this interval's duty activity + OST mask on-device
-            act = self._act_fn(self._statics, t)
-            self._state["act"] = jax.device_put(act, self.device)
-            self._mask = np.asarray(
-                self._mask_fn(self._state["dirty"], self._statics, act))
-            self._wl_seen = core._wl_version
-        noise = self.cluster._noise_for(self._mask)
-        state, totals, mask_next = self._step_fn(self._state, self._statics,
-                                                 t, dt, noise)
-        self._state = state
-        self._mask = np.asarray(mask_next)
-        self.host_stale = True
+        rec = _telemetry()
+        with rec.span("fleet.step", cat="fleet"):
+            core = self.core
+            self._take_ownership()
+            if self.device_stale or self._state is None:
+                self._push()
+            self._refresh_statics()
+            if self._mask is None or self._wl_seen != core._wl_version:
+                # no valid predicted mask (fresh push or workload
+                # mutation): recompute this interval's duty activity + OST
+                # mask on-device (``act`` is computed there: its put is
+                # no transfer)
+                with rec.span("fleet.mask", cat="fleet"):
+                    act = self._act_fn(self._statics, t)
+                    self._state["act"] = jax.device_put(act, self.device)
+                    self._mask = np.asarray(
+                        self._mask_fn(self._state["dirty"], self._statics,
+                                      act))
+                    self._wl_seen = core._wl_version
+                if rec.enabled:
+                    rec.count("fleet.d2h_bytes", self._mask.nbytes)
+            with rec.span("fleet.noise", cat="fleet"):
+                noise = self.cluster._noise_for(self._mask)
+            state, totals, mask_next = self._step_fn(
+                self._state, self._statics, t, dt, noise)
+            self._state = state
+            self._mask = np.asarray(mask_next)
+            self.host_stale = True
+            if rec.enabled:
+                rec.count("fleet.h2d_bytes", noise.nbytes)
+                rec.count("fleet.d2h_bytes", self._mask.nbytes)
         return totals
 
 
@@ -620,29 +666,39 @@ class ShardedDeviceFleet:
     # ------------------------------------------------------- host <-> device
     def _push(self) -> None:
         core, cl = self.core, self.cluster
-        self._states = []
-        for ix, dev in zip(self.shard_idx, self.devices):
-            st = {
-                "dirty": core.dirty_bytes[ix],
-                "last_drain": core.last_drain[ix],
-                "read": {f: getattr(core.read, f)[ix] for f in OP_FIELDS},
-                "write": {f: getattr(core.write, f)[ix] for f in OP_FIELDS},
-                "dirty_peak": core.dirty_peak_bytes[ix],
-                "inflight_peak": core.inflight_peak[ix],
-            }
-            self._states.append(jax.device_put(st, dev))
-        self._ost_state = jax.device_put(
-            {"ost_wait": cl.wait_s, "ost_util": cl.utilization,
-             "ost_inflight": cl.inflight,
-             "ost_served_bytes": cl.served_bytes,
-             "ost_served_rpcs": cl.served_rpcs}, self.primary)
+        rec = _telemetry()
+        with rec.span("fleet.push", cat="fleet"):
+            self._states = []
+            for ix, dev in zip(self.shard_idx, self.devices):
+                st = {
+                    "dirty": core.dirty_bytes[ix],
+                    "last_drain": core.last_drain[ix],
+                    "read": {f: getattr(core.read, f)[ix]
+                             for f in OP_FIELDS},
+                    "write": {f: getattr(core.write, f)[ix]
+                              for f in OP_FIELDS},
+                    "dirty_peak": core.dirty_peak_bytes[ix],
+                    "inflight_peak": core.inflight_peak[ix],
+                }
+                self._states.append(jax.device_put(st, dev))
+            self._ost_state = jax.device_put(
+                {"ost_wait": cl.wait_s, "ost_util": cl.utilization,
+                 "ost_inflight": cl.inflight,
+                 "ost_served_bytes": cl.served_bytes,
+                 "ost_served_rpcs": cl.served_rpcs}, self.primary)
+            if rec.enabled:
+                rec.count("fleet.h2d_bytes", _nbytes(self._states)
+                          + _nbytes(self._ost_state))
         self.device_stale = False
         self._mask = None
 
     def _refresh_statics(self) -> None:
         core = self.core
-        core._ensure_static()
-        if self._static_seen != core._static_version:
+        if _statics_current(core, self._static_seen):
+            return
+        rec = _telemetry()
+        with rec.span("fleet.statics", cat="fleet"):
+            core._ensure_static()
             st = core._static
             self._statics = []
             for ix, dev in zip(self.shard_idx, self.devices):
@@ -652,25 +708,32 @@ class ShardedDeviceFleet:
                                            np.asarray(st.ch_ost)[ix])
                 self._statics.append(jax.device_put(sl, dev))
             self._static_seen = core._static_version
+            if rec.enabled:
+                rec.count("fleet.h2d_bytes", _nbytes(self._statics))
 
     def sync_host(self) -> None:
         core, cl = self.core, self.cluster
-        for ix, st in zip(self.shard_idx, self._states):
-            h = jax.tree.map(np.asarray, st)
-            core.dirty_bytes[ix] = h["dirty"]
-            core.last_drain[ix] = h["last_drain"]
-            for f in OP_FIELDS:
-                getattr(core.read, f)[ix] = h["read"][f]
-                getattr(core.write, f)[ix] = h["write"][f]
-            core.dirty_peak_bytes[ix] = h["dirty_peak"]
-            core.inflight_peak[ix] = h["inflight_peak"]
-        ost = jax.tree.map(np.asarray, self._ost_state)
-        core.waits[:, :] = ost["ost_wait"][None, :]
-        cl.wait_s[:] = ost["ost_wait"]
-        cl.utilization[:] = ost["ost_util"]
-        cl.inflight[:] = ost["ost_inflight"]
-        cl.served_bytes[:] = ost["ost_served_bytes"]
-        cl.served_rpcs[:] = ost["ost_served_rpcs"]
+        rec = _telemetry()
+        with rec.span("fleet.sync_host", cat="fleet"):
+            for ix, st in zip(self.shard_idx, self._states):
+                h = jax.tree.map(np.asarray, st)
+                core.dirty_bytes[ix] = h["dirty"]
+                core.last_drain[ix] = h["last_drain"]
+                for f in OP_FIELDS:
+                    getattr(core.read, f)[ix] = h["read"][f]
+                    getattr(core.write, f)[ix] = h["write"][f]
+                core.dirty_peak_bytes[ix] = h["dirty_peak"]
+                core.inflight_peak[ix] = h["inflight_peak"]
+            ost = jax.tree.map(np.asarray, self._ost_state)
+            core.waits[:, :] = ost["ost_wait"][None, :]
+            cl.wait_s[:] = ost["ost_wait"]
+            cl.utilization[:] = ost["ost_util"]
+            cl.inflight[:] = ost["ost_inflight"]
+            cl.served_bytes[:] = ost["ost_served_bytes"]
+            cl.served_rpcs[:] = ost["ost_served_rpcs"]
+            if rec.enabled:
+                rec.count("fleet.d2h_bytes", _nbytes(self._states)
+                          + _nbytes(self._ost_state))
         self.host_stale = False
 
     def _take_ownership(self) -> None:
@@ -690,6 +753,11 @@ class ShardedDeviceFleet:
         """One barrier interval across all shard devices. Returns the
         per-shard cumulative read+write app_bytes device arrays (shard
         order), for the runtime's throughput accounting."""
+        rec = _telemetry()
+        with rec.span("fleet.step", cat="fleet"):
+            return self._step(rec, t, dt)
+
+    def _step(self, rec, t: float, dt: float) -> List:
         core = self.core
         self._take_ownership()
         if self.device_stale or self._ost_state is None:
@@ -711,15 +779,22 @@ class ShardedDeviceFleet:
             merged = part if merged is None else merged + part
 
         if self._mask is None or self._wl_seen != core._wl_version:
-            cnt = None
-            for st, sl, dev in zip(self._states, self._statics,
-                                   self.devices):
-                c = jax.device_put(self._lanes_fn(sl, st["dirty"], t),
-                                   self.primary)
-                cnt = c if cnt is None else cnt + c
-            self._mask = np.asarray(cnt) > 0.0
-            self._wl_seen = core._wl_version
-        noise = self.cluster._noise_for(self._mask)
+            with rec.span("fleet.mask", cat="fleet"):
+                cnt = None
+                for st, sl, dev in zip(self._states, self._statics,
+                                       self.devices):
+                    c = jax.device_put(self._lanes_fn(sl, st["dirty"], t),
+                                       self.primary)
+                    cnt = c if cnt is None else cnt + c
+                cnt = np.asarray(cnt)
+                self._mask = cnt > 0.0
+                self._wl_seen = core._wl_version
+            if rec.enabled:
+                rec.count("fleet.d2h_bytes", cnt.nbytes)
+        with rec.span("fleet.noise", cat="fleet"):
+            noise = self.cluster._noise_for(self._mask)
+        if rec.enabled:
+            rec.count("fleet.h2d_bytes", noise.nbytes)
 
         ost_out, scale_out, new_wait = self._resolve_fn(
             self._ost_state, merged, noise, dt)

@@ -62,6 +62,21 @@ def test_nets_learn(arch_cls):
     assert acc > 0.75
 
 
+@pytest.mark.parametrize("arch_cls", [FCNN, VanillaRNN, TCN])
+def test_nets_stay_float32_with_x64_on(arch_cls):
+    """``Simulation(backend="soa-jax")`` turns x64 on for the whole
+    process; the nets must still train in float32 afterwards (the TCN's
+    convolution refuses mixed dtypes)."""
+    import jax
+    X, y = _radial_data()
+    with jax.enable_x64(True):
+        m = train_net(arch_cls(X.shape[1]), X[:600], y[:600],
+                      X[600:800], y[600:800], epochs=2)
+        dtypes = {str(a.dtype) for a in jax.tree.leaves(m.params)}
+        assert m.predict(X[:8]).shape == (8,)
+    assert dtypes == {"float32"}
+
+
 def test_gbdt_save_load_roundtrip():
     X, y = _xor_data(n=1000)
     m = train_gbdt(X, y, n_trees=30, depth=4)
