@@ -16,27 +16,33 @@ collector, Fig 4 step 5), never across the cluster.
 
 Within the pluggable policy layer (``repro.core.policies``) this class is
 the per-client *state shell* that :class:`~repro.core.policies.CaratPolicy`
-hosts: ``observe()`` is the shared sampling/stage-machine path both the
-scalar loop and the batched fleet engine run (bit-identical by
-construction), ``actuate()`` applies a stage-1 decision produced either
-locally (``__call__``) or by the policy's batched ``decide_many``.
+hosts: ``observe()`` is the per-client sampling/stage-machine path, and
+``actuate()`` applies a stage-1 decision produced either locally
+(``__call__``) or by the policy's batched ``decide_many``. A shell's
+state lives at its row of a :class:`ControllerStore`; for a fleet of SoA
+clients the policy observes every row at once with
+:meth:`ControllerStore.probe`, the array twin of ``observe()``, which
+tests hold bit-identical to it.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence
+from dataclasses import dataclass
+from typing import Dict, List, NamedTuple, Optional, Sequence
 
 import numpy as np
 
 from repro.config.types import CaratConfig
 from repro.core.cache_tuner import CacheDemand, cache_allocation
+from repro.core.metrics import FEATURE_NAMES, Metrics, compute_metrics_many
 from repro.core.policy import CaratSpaces
 from repro.core.rpc_tuner import _TunerBase, make_tuner
 from repro.core.runtime.telemetry.clock import perf_s
 from repro.core.runtime.telemetry.recorder import active as _telemetry
-from repro.core.snapshot import Snapshot, SnapshotBuilder
+from repro.core.snapshot import Snapshot, SnapshotBuilder, feature_rows
 from repro.storage.client import IOClient
 from repro.storage.params import PAGE_SIZE
+from repro.storage.soa import OP_FIELDS
+from repro.storage.stats import ClientStats, OpCounters
 from repro.utils.rng import RngStream
 
 
@@ -71,14 +77,293 @@ class _AppSignature:
         return False
 
 
-@dataclass
+# ------------------------------------------------------------ shell state
+# Snapshot fields kept per history entry beside its read and write metrics
+_SNAP_VALS = ("t", "read_active", "write_active", "read_app_bytes",
+              "write_app_bytes", "dirty_peak_bytes", "inflight_peak",
+              "window_pages", "in_flight", "dirty_cache_mb",
+              "read_app_requests", "write_app_requests")
+_SNAP_CASTS = {"read_active": bool, "write_active": bool,
+               "window_pages": int, "in_flight": int, "dirty_cache_mb": int}
+_WINDOW, _IN_FLIGHT = _SNAP_VALS.index("window_pages"), _SNAP_VALS.index(
+    "in_flight")
+_OP_COL = {f: k for k, f in enumerate(OP_FIELDS)}
+OPS = ("read", "write")
+
+
+class ControllerStore:
+    """The observe state of CARAT controller shells, one row per shell.
+
+    A shell reads and writes its state at its row (a :class:`_Slot`), as
+    ``_SoAStatsView`` reads a client's counters from ``SoACore``: the
+    snapshot builder's previous sample and history, the stage machine,
+    the re-probe flags and the stage factors. A lone shell owns a
+    one-row store. ``CaratPolicy`` gathers its fleet's shells into one
+    store, so that :meth:`probe` advances every shell at once.
+    """
+
+    def __init__(self, n: int, history_k: int = 1):
+        self.n = n
+        self.history_k = history_k
+        h = history_k + 1
+        z = np.zeros
+        # snapshot builder: the previous sample (cumulative read and
+        # write counters, gauges, tunables) and the history, oldest first
+        self.has_prev = z(n, bool)
+        self.prev_ops = z((n, 2, len(OP_FIELDS)))
+        self.prev_gauges = z((n, 3))      # dirty, dirty peak, in-flight peak
+        self.prev_cfg = z((n, 3), np.int64)   # window, in flight, cache MB
+        self.n_hist = z(n, np.int64)
+        self.hist_metrics = z((n, h, 2, 6))   # read, write Table II rows
+        self.hist_vals = z((n, h, len(_SNAP_VALS)))
+        self.snap_time = z(n)
+        self.snap_count = z(n, np.int64)
+        # stage machine and phase re-probe
+        self.inactive_s = z(n)
+        self.was_inactive_long = z(n, bool)
+        self.has_sig = z(n, bool)
+        # last active signature: read share, read and write request size
+        # (NaN where the signature has none)
+        self.sig = np.full((n, 3), np.nan)
+        self.last_reprobe_t = np.full(n, -np.inf)
+        self.reprobe_pending = z(n, bool)
+        self.bootstrap_pending = z(n, bool)
+        # stage factors (Algorithm 2's demand)
+        self.sf_saw = z(n, bool)
+        self.sf_peak_cache = z(n)
+        self.sf_peak_inflight = z(n)
+        self.sf_write_rpcs = z(n)
+        self.sf_total_rpcs = z(n)
+
+    def _arrays(self) -> List[str]:
+        return [k for k, v in vars(self).items() if isinstance(v, np.ndarray)]
+
+    def take(self, rows: Sequence[int]) -> "ControllerStore":
+        """A new store holding copies of ``rows``."""
+        out = ControllerStore(0, self.history_k)
+        out.n = len(rows)
+        for k in self._arrays():
+            setattr(out, k, getattr(self, k)[list(rows)])
+        return out
+
+    def put(self, rows: Sequence[int], src: "ControllerStore",
+            src_rows: Sequence[int]) -> None:
+        """Copy ``src``'s ``src_rows`` into ``rows``."""
+        for k in self._arrays():
+            getattr(self, k)[list(rows)] = getattr(src, k)[list(src_rows)]
+
+    @classmethod
+    def gather(cls, slots: Sequence["_Slot"]) -> "ControllerStore":
+        """One store holding every slot's row, in order; each slot is
+        repointed at its new row."""
+        ks = {s.store.history_k for s in slots}
+        if len(ks) != 1:
+            raise ValueError(f"shells keep histories of {sorted(ks)} "
+                             f"probes; one store holds one depth")
+        out = cls(len(slots), ks.pop())
+        groups: Dict[int, tuple] = {}
+        for i, s in enumerate(slots):
+            g = groups.setdefault(id(s.store), (s.store, [], []))
+            g[1].append(i)
+            g[2].append(s.row)
+        for src, rows, src_rows in groups.values():
+            out.put(rows, src, src_rows)
+        for i, s in enumerate(slots):
+            s.store, s.row = out, i
+        return out
+
+    def probe(self, ops: np.ndarray, gauges: np.ndarray, tunables: np.ndarray,
+              t: float, dt: float, cfg: CaratConfig,
+              default: tuple) -> "Probe":
+        """One probe of every row: :meth:`CaratController.observe` as
+        array operations, in the same order and with the same float64
+        arithmetic, so each row ends as that shell's observe leaves it.
+
+        ``ops`` is ``(n, 2, len(OP_FIELDS))``, every row's cumulative read
+        and write counters; ``gauges`` ``(n, 3)`` its dirty bytes, dirty
+        peak and in-flight peak; ``tunables`` ``(n, 3)`` its RPC window,
+        RPCs in flight and dirty-cache MB. The per-client actions (stage-2
+        boundary marks, re-probe resets, bootstrap picks) are the
+        caller's, from the returned masks.
+        """
+        t0 = perf_s()
+        n = self.n
+        window, in_flight, cache_mb = tunables.T
+        dirty, dirty_peak, inflight_peak = gauges.T
+        # ---- SnapshotBuilder.sample: difference, Table II, history ----
+        snap = self.has_prev.copy()
+        d = ops - self.prev_ops
+        met = np.stack([compute_metrics_many(
+            {f: d[:, j, k] for f, k in _OP_COL.items()}, dirty,
+            self.prev_gauges[:, 0], window, in_flight, cache_mb, op,
+            cfg.probe_interval_s) for j, op in enumerate(OPS)], axis=1)
+        rd_b = d[:, 0, _OP_COL["app_bytes"]]
+        wr_b = d[:, 1, _OP_COL["app_bytes"]]
+        rd_q = d[:, 0, _OP_COL["app_requests"]]
+        wr_q = d[:, 1, _OP_COL["app_requests"]]
+        rd_act, wr_act = rd_q > 0, wr_q > 0
+        vals = np.stack([np.full(n, t), rd_act, wr_act, rd_b, wr_b,
+                         dirty_peak, inflight_peak, window, in_flight,
+                         cache_mb, rd_q, wr_q], axis=1)
+        self.hist_metrics[snap, :-1] = self.hist_metrics[snap, 1:]
+        self.hist_metrics[snap, -1] = met[snap]
+        self.hist_vals[snap, :-1] = self.hist_vals[snap, 1:]
+        self.hist_vals[snap, -1] = vals[snap]
+        self.n_hist[snap] = np.minimum(self.n_hist[snap] + 1,
+                                       self.history_k + 1)
+        self.has_prev[:] = True
+        self.prev_ops[:] = ops
+        self.prev_gauges[:] = gauges
+        self.prev_cfg[:] = tunables
+        self.snap_count += 1
+        self.snap_time += (perf_s() - t0) / max(n, 1)
+
+        # ---- _StageFactors.update (every row with a snapshot) ----
+        active = snap & (rd_act | wr_act)
+        self.sf_saw |= active
+        rd, wr = met[:, 0], met[:, 1]
+        cache = wr[:, 4] * (cache_mb * 1024.0 * 1024.0)
+        # max(a, b) keeps a unless b > a
+        self.sf_peak_cache = np.where(snap & (cache > self.sf_peak_cache),
+                                      cache, self.sf_peak_cache)
+        infl = inflight_peak * window * float(PAGE_SIZE)
+        self.sf_peak_inflight = np.where(
+            snap & (infl > self.sf_peak_inflight), infl,
+            self.sf_peak_inflight)
+        self.sf_write_rpcs = np.where(snap, self.sf_write_rpcs + wr[:, 3],
+                                      self.sf_write_rpcs)
+        self.sf_total_rpcs = np.where(
+            snap, self.sf_total_rpcs + (rd[:, 3] + wr[:, 3]),
+            self.sf_total_rpcs)
+
+        # ---- stage machine ----
+        idle = snap & ~active
+        self.inactive_s = np.where(idle, self.inactive_s + dt,
+                                   self.inactive_s)
+        self.was_inactive_long |= idle & (self.inactive_s
+                                          >= cfg.inactive_threshold_s)
+        boundary = active & self.was_inactive_long
+        self.was_inactive_long &= ~active
+        self.inactive_s[active] = 0.0
+
+        # ---- phase re-probe (_AppSignature.of / changed_from) ----
+        reset = np.zeros(n, bool)
+        if cfg.reprobe_on_change:
+            total = rd_b + wr_b
+            with np.errstate(divide="ignore", invalid="ignore"):
+                sig = np.stack([np.where(total > 0, rd_b / total, 0.0),
+                                np.where(rd_q > 0.5, rd_b / rd_q, np.nan),
+                                np.where(wr_q > 0.5, wr_b / wr_q, np.nan)],
+                               axis=1)
+            prev = self.sig
+            changed = (((prev[:, 0] >= 0.7) & (sig[:, 0] <= 0.3))
+                       | ((prev[:, 0] <= 0.3) & (sig[:, 0] >= 0.7)))
+            for j in (1, 2):
+                a, b = prev[:, j], sig[:, j]
+                both = ~np.isnan(a) & ~np.isnan(b)
+                changed |= both & (np.fmax(a, b)
+                                   > np.fmin(a, b) * cfg.reprobe_req_ratio)
+            changed &= self.has_sig
+            self.sig[active] = sig[active]
+            self.has_sig |= active
+            self.reprobe_pending |= active & changed
+            fire = active & self.reprobe_pending & (
+                t - self.last_reprobe_t >= cfg.reprobe_cooldown_s)
+            self.reprobe_pending &= ~fire
+            self.last_reprobe_t[fire] = t
+            self.bootstrap_pending |= fire
+            reset = fire & ~((window == default[0])
+                             & (in_flight == default[1]))
+
+        # ---- stage 1: features; the bootstrap pick takes its row ----
+        op = np.where(rd[:, 3] >= wr[:, 3], 0, 1)
+        has_feats = active & ~reset & (self.n_hist >= 2)
+        boot = has_feats & self.bootstrap_pending
+        self.bootstrap_pending &= ~boot
+        rows = np.arange(n)
+        feats = feature_rows(met[rows, op],
+                             self.hist_metrics[rows, -2, op],
+                             window, in_flight)
+        return Probe(boundary=boundary, reset=reset, bootstrap=boot,
+                     pending=has_feats & ~boot, op=op, feats=feats)
+
+
+class Probe(NamedTuple):
+    """What :meth:`ControllerStore.probe` found, per row: the stage-2
+    ``boundary`` crossings, the re-probe ``reset`` to the default, the
+    ``bootstrap`` picks and the ``pending`` stage-1 decisions, with each
+    row's dominant ``op`` (index into ``OPS``) and feature row."""
+    boundary: np.ndarray
+    reset: np.ndarray
+    bootstrap: np.ndarray
+    pending: np.ndarray
+    op: np.ndarray
+    feats: np.ndarray
+
+
+class _Slot:
+    """Where one shell's state lives: a row of a :class:`ControllerStore`.
+
+    Shared by the shell, its snapshot builder and its stage-factor views,
+    so repointing it moves all of them. A pickled slot carries a one-row
+    store of its own."""
+
+    __slots__ = ("store", "row")
+
+    def __init__(self, store: ControllerStore, row: int):
+        self.store = store
+        self.row = row
+
+    def __reduce__(self):
+        return (_Slot, (self.store.take([self.row]), 0))
+
+
+class _RowField:
+    """An attribute kept at the owner's row of one store array."""
+
+    __slots__ = ("name", "cast")
+
+    def __init__(self, name: str, cast):
+        self.name = name
+        self.cast = cast
+
+    def __get__(self, obj, owner=None):
+        if obj is None:
+            return self
+        s = obj._slot
+        return self.cast(getattr(s.store, self.name)[s.row])
+
+    def __set__(self, obj, value) -> None:
+        s = obj._slot
+        getattr(s.store, self.name)[s.row] = value
+
+
 class _StageFactors:
-    """Factors accumulated over one I/O-active stage (for Algorithm 2)."""
-    peak_cache_bytes: float = 0.0
-    peak_inflight_bytes: float = 0.0
-    write_rpcs: float = 0.0
-    total_rpcs: float = 0.0
-    saw_activity: bool = False
+    """Factors accumulated over one I/O-active stage (for Algorithm 2),
+    at a shell's row; ``_StageFactors()`` is a zeroed set of its own."""
+
+    FIELDS = ("peak_cache_bytes", "peak_inflight_bytes", "write_rpcs",
+              "total_rpcs", "saw_activity")
+    __slots__ = ("_slot",)
+    peak_cache_bytes = _RowField("sf_peak_cache", float)
+    peak_inflight_bytes = _RowField("sf_peak_inflight", float)
+    write_rpcs = _RowField("sf_write_rpcs", float)
+    total_rpcs = _RowField("sf_total_rpcs", float)
+    saw_activity = _RowField("sf_saw", bool)
+
+    def __init__(self, slot: Optional[_Slot] = None):
+        self._slot = slot if slot is not None else _Slot(
+            ControllerStore(1), 0)
+
+    def __repr__(self) -> str:
+        return "_StageFactors(" + ", ".join(
+            f"{f}={getattr(self, f)!r}" for f in self.FIELDS) + ")"
+
+    def clear(self) -> None:
+        """Start a new stage: every factor back to zero."""
+        self.peak_cache_bytes = self.peak_inflight_bytes = 0.0
+        self.write_rpcs = self.total_rpcs = 0.0
+        self.saw_activity = False
 
     def update(self, snap: Snapshot) -> None:
         self.saw_activity = self.saw_activity or snap.active
@@ -91,6 +376,107 @@ class _StageFactors:
         # RPC mix for factor (3)
         self.write_rpcs += snap.write.data_volume
         self.total_rpcs += vol
+
+
+class _HistoryRows:
+    """``builder.history`` at a shell's row: its last snapshots, oldest
+    first, standing in for the ``deque(maxlen=history_k + 1)``."""
+
+    __slots__ = ("_slot",)
+
+    def __init__(self, slot: _Slot):
+        self._slot = slot
+
+    @property
+    def maxlen(self) -> int:
+        return self._slot.store.history_k + 1
+
+    def __len__(self) -> int:
+        s = self._slot
+        return int(s.store.n_hist[s.row])
+
+    def __getitem__(self, j: int) -> Snapshot:
+        n = len(self)
+        if j < 0:
+            j += n
+        if not 0 <= j < n:
+            raise IndexError("snapshot history index out of range")
+        s = self._slot
+        k = self.maxlen - n + j
+        rd, wr = s.store.hist_metrics[s.row, k].tolist()
+        vals = dict(zip(_SNAP_VALS, s.store.hist_vals[s.row, k].tolist()))
+        for name, cast in _SNAP_CASTS.items():
+            vals[name] = cast(vals[name])
+        return Snapshot(read=Metrics(*rd), write=Metrics(*wr), **vals)
+
+    def __iter__(self):
+        return (self[j] for j in range(len(self)))
+
+    def append(self, snap: Snapshot) -> None:
+        s = self._slot
+        st, r = s.store, s.row
+        st.hist_metrics[r, :-1] = st.hist_metrics[r, 1:].copy()
+        st.hist_vals[r, :-1] = st.hist_vals[r, 1:].copy()
+        st.hist_metrics[r, -1] = [[getattr(m, f) for f in FEATURE_NAMES]
+                                  for m in (snap.read, snap.write)]
+        st.hist_vals[r, -1] = [getattr(snap, f) for f in _SNAP_VALS]
+        st.n_hist[r] = min(int(st.n_hist[r]) + 1, self.maxlen)
+
+
+class _ShellBuilder(SnapshotBuilder):
+    """The shell's :class:`SnapshotBuilder`, with its previous sample,
+    history and Table VIII accounting at the shell's row."""
+
+    snapshot_time_total = _RowField("snap_time", float)
+    snapshot_count = _RowField("snap_count", int)
+
+    def __init__(self, slot: _Slot, interval_s: float, history_k: int):
+        self._slot = slot
+        self.interval_s = interval_s
+        self.history_k = history_k
+
+    @property
+    def history(self) -> _HistoryRows:
+        return _HistoryRows(self._slot)
+
+    def feature_vector(self, op: str) -> Optional[np.ndarray]:
+        # SnapshotBuilder.feature_vector, read straight from the rows
+        s = self._slot
+        st, r = s.store, s.row
+        if st.n_hist[r] < 2:
+            return None
+        prev, cur = st.hist_metrics[r, -2:, OPS.index(op)]
+        vals = st.hist_vals[r, -1]
+        return feature_rows(cur.astype(np.float32), prev.astype(np.float32),
+                            int(vals[_WINDOW]), int(vals[_IN_FLIGHT]))
+
+    @property
+    def _prev(self) -> Optional[ClientStats]:
+        s = self._slot
+        st, r = s.store, s.row
+        if not st.has_prev[r]:
+            return None
+        rd, wr = st.prev_ops[r].tolist()
+        dirty, peak, inflight = st.prev_gauges[r].tolist()
+        window, in_flight, cache_mb = st.prev_cfg[r].tolist()
+        return ClientStats(read=OpCounters(*rd), write=OpCounters(*wr),
+                           dirty_bytes=dirty, dirty_peak_bytes=peak,
+                           inflight_peak=inflight, rpc_window_pages=window,
+                           rpcs_in_flight=in_flight, dirty_cache_mb=cache_mb)
+
+    @_prev.setter
+    def _prev(self, stats: Optional[ClientStats]) -> None:
+        s = self._slot
+        st, r = s.store, s.row
+        st.has_prev[r] = stats is not None
+        if stats is None:
+            return
+        st.prev_ops[r] = [[getattr(c, f) for f in OP_FIELDS]
+                          for c in (stats.read, stats.write)]
+        st.prev_gauges[r] = (stats.dirty_bytes, stats.dirty_peak_bytes,
+                             stats.inflight_peak)
+        st.prev_cfg[r] = (stats.rpc_window_pages, stats.rpcs_in_flight,
+                          stats.dirty_cache_mb)
 
 
 class NodeCacheArbiter:
@@ -165,7 +551,7 @@ class NodeCacheArbiter:
             # keep accumulating toward their own next boundary. (Deferred
             # crossings have already cleared their flag, hence _crossed.)
             if m.was_inactive_long or m in self._crossed:
-                m.stage_factors = _StageFactors()
+                m.stage_factors.clear()
         self._crossed.clear()
         self.pending = False
 
@@ -177,7 +563,7 @@ class NodeCacheArbiter:
             if m.client is not None:
                 m.client.set_cache_limit(v)
             if m.was_inactive_long or m in self._crossed:
-                m.stage_factors = _StageFactors()
+                m.stage_factors.clear()
         self._crossed.clear()
         self.pending = False
 
@@ -213,12 +599,18 @@ class CaratController:
         cfg: Optional[CaratConfig] = None,
         rng: Optional[RngStream] = None,
         arbiter: Optional[NodeCacheArbiter] = None,
+        slot: Optional[_Slot] = None,
     ):
         self.client_id = client_id
         self.cfg = cfg or CaratConfig()
         self.spaces = spaces
-        self.builder = SnapshotBuilder(interval_s=self.cfg.probe_interval_s,
-                                       history_k=self.cfg.history_k)
+        # the row this shell's observe state lives at (a fresh one, or a
+        # one-row store of its own); its builder shares it
+        self._slot = slot if slot is not None else _Slot(
+            ControllerStore(1, self.cfg.history_k), 0)
+        self.builder = _ShellBuilder(self._slot,
+                                     interval_s=self.cfg.probe_interval_s,
+                                     history_k=self.cfg.history_k)
         probs = {op: (m.predict_proba if hasattr(m, "predict_proba") else m)
                  for op, m in models.items()}
         self.tuner: _TunerBase = make_tuner(
@@ -229,20 +621,51 @@ class CaratController:
         self.arbiter = arbiter
         if arbiter is not None:
             arbiter.register(self)
-        # stage machine
-        self.inactive_s = 0.0
-        self.was_inactive_long = False
-        self.stage_factors = _StageFactors()
-        # phase-change re-probing state (replayed/dynamic workloads)
-        self._last_sig: Optional[_AppSignature] = None
-        self._last_reprobe_t = -float("inf")
-        self._reprobe_pending = False
-        self._bootstrap_pending = False
+        # the stage machine, the phase-change re-probing state and the
+        # stage factors start at the row's defaults (see the properties)
         self.client: Optional[IOClient] = None
         # Table VIII accounting
         self.apply_time_total = 0.0
         self.apply_count = 0
         self.decisions: List[tuple] = []
+
+    # --- state at the shell's row ------------------------------------------
+    # stage machine
+    inactive_s = _RowField("inactive_s", float)
+    was_inactive_long = _RowField("was_inactive_long", bool)
+    # phase-change re-probing state (replayed/dynamic workloads)
+    _last_reprobe_t = _RowField("last_reprobe_t", float)
+    _reprobe_pending = _RowField("reprobe_pending", bool)
+    _bootstrap_pending = _RowField("bootstrap_pending", bool)
+
+    @property
+    def _last_sig(self) -> Optional[_AppSignature]:
+        s = self._slot
+        if not s.store.has_sig[s.row]:
+            return None
+        share, rr, rw = s.store.sig[s.row].tolist()
+        return _AppSignature(read_share=share,
+                             req_read=None if np.isnan(rr) else rr,
+                             req_write=None if np.isnan(rw) else rw)
+
+    @_last_sig.setter
+    def _last_sig(self, sig: Optional[_AppSignature]) -> None:
+        s = self._slot
+        s.store.has_sig[s.row] = sig is not None
+        if sig is not None:
+            s.store.sig[s.row] = [sig.read_share] + [
+                np.nan if v is None else v
+                for v in (sig.req_read, sig.req_write)]
+
+    @property
+    def stage_factors(self) -> _StageFactors:
+        return _StageFactors(self._slot)
+
+    @stage_factors.setter
+    def stage_factors(self, value: _StageFactors) -> None:
+        mine = _StageFactors(self._slot)
+        for f in _StageFactors.FIELDS:
+            setattr(mine, f, getattr(value, f))
 
     # --- Simulation controller interface ---------------------------------------
     def observe(self, client: IOClient, t: float,
@@ -365,3 +788,8 @@ class CaratController:
                               + self.apply_time_total
                               / max(self.apply_count, 1)) * 1e3,
         }
+
+
+# What ControllerStore.probe reproduces: shells whose class observes
+# otherwise (a subclass's override) are observed one by one.
+STOCK_OBSERVE = CaratController.observe

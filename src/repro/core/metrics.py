@@ -10,7 +10,7 @@ model's internal ground truth, preserving the observability contract.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict
+from typing import Mapping
 
 import numpy as np
 
@@ -83,6 +83,51 @@ def compute_metrics(
         dirty_cache_util=float(np.clip(dirty_util, 0.0, 1.2)),
         est_cache_update=float(est_update),
     )
+
+
+def compute_metrics_many(
+    d: Mapping[str, np.ndarray],
+    dirty: np.ndarray,
+    prev_dirty: np.ndarray,
+    window_pages: np.ndarray,
+    in_flight: np.ndarray,
+    cache_mb: np.ndarray,
+    op: str,
+    interval_s: float,
+) -> np.ndarray:
+    """:func:`compute_metrics` for many clients: ``(n, 6)`` float64 rows.
+
+    ``d`` maps each counter of ``op`` to its ``(n,)`` deltas; the gauges
+    and tunables are ``(n,)`` arrays. Every row takes the same operations
+    in the same order as the scalar function, so it equals that client's
+    ``Metrics.vector()`` before the float32 cast, bit for bit. Rows whose
+    deltas are meaningless (no previous sample) compute garbage quietly;
+    the caller masks them.
+    """
+    window = np.maximum(window_pages, 1)
+    inflight_cap = np.maximum(in_flight, 1)
+    cache_bytes = np.maximum(cache_mb, 1) * 1024.0 * 1024.0
+    rpcs = d["rpc_count"]
+    pages = d["rpc_pages"]
+    with np.errstate(divide="ignore", invalid="ignore"):
+        page_util = np.where(rpcs > 0, pages / rpcs / window, 0.0)
+        n_chan = np.maximum(d["channel_time"] / interval_s, 1.0)
+        chan_util = d["inflight_time"] / interval_s / inflight_cap / n_chan
+        unit_lat = np.where(pages > 0, d["lat_sum_s"] / pages, 0.0)
+        volume = d["rpc_bytes"] / n_chan
+        if op == "write":
+            dirty_util = dirty / cache_bytes
+            # max(0.0, x) keeps 0.0 unless x > 0.0
+            est = d["app_bytes"] - d["rpc_bytes"] - (dirty - prev_dirty)
+            est_update = np.where(est > 0.0, est, 0.0)
+        else:
+            dirty_util = np.zeros_like(volume)
+            est_update = np.zeros_like(volume)
+    return np.stack([np.clip(page_util, 0.0, 1.5),
+                     np.clip(chan_util, 0.0, 1.5),
+                     unit_lat, volume,
+                     np.clip(dirty_util, 0.0, 1.2),
+                     est_update], axis=1)
 
 
 def normalize_features(vec: np.ndarray) -> np.ndarray:

@@ -2,13 +2,16 @@
 
 This module owns the fleet-scale decision engine. The decision
 semantics are gated: per-client :class:`CaratController` shells run the
-shared ``observe()`` path (snapshot, stage machine, stage-2 boundary
-marking, phase re-probe) in member order, stage-1 proposals come from
-one vectorized ``propose_many`` per probe, and pending stage-2 node
-boundaries drain into one batched ``cache_allocation_many`` call with
-the slot-ordered GBDT/write-share accumulation intact — so decisions
-stay bit-identical to the per-client loop (``bench_fleet_scale``,
-``bench_cache_fleet``, ``bench_replay`` all gate this).
+``observe()`` path (snapshot, stage machine, stage-2 boundary marking,
+phase re-probe) in member order, or, for a fleet of SoA clients, one
+batched pass over every shell's row does the same
+(:meth:`~repro.core.controller.ControllerStore.probe`); stage-1
+proposals come from one vectorized ``propose_many`` per probe, and
+pending stage-2 node boundaries drain into one batched
+``cache_allocation_many`` call with the slot-ordered GBDT/write-share
+accumulation intact — so decisions stay bit-identical to the per-client
+loop (``bench_fleet_scale``, ``bench_cache_fleet``, ``bench_replay`` all
+gate this).
 
 Construction comes in two shapes:
 
@@ -57,7 +60,8 @@ from repro.config.types import CaratConfig
 from repro.core.cache_tuner import (CacheDemand, CacheDemandBatch,
                                     cache_allocation, cache_allocation_many,
                                     trade_node_budgets)
-from repro.core.controller import CaratController, NodeCacheArbiter
+from repro.core.controller import (OPS, STOCK_OBSERVE, CaratController,
+                                   ControllerStore, NodeCacheArbiter, _Slot)
 from repro.core.ml.gbdt import ObliviousGBDT
 from repro.core.policies.base import TuningPolicy, resolve_bound_clients
 from repro.core.policy import CaratSpaces
@@ -65,6 +69,7 @@ from repro.core.rpc_tuner import _TunerBase, make_tuner
 from repro.core.runtime.telemetry.clock import perf_s
 from repro.core.runtime.telemetry.recorder import active as _telemetry
 from repro.storage.client import IOClient
+from repro.storage.soa import SoAClientView
 from repro.utils.rng import RngStream
 
 NodeBudgets = Union[float, Mapping[object, float], None]
@@ -175,19 +180,23 @@ def wire_controllers(
         if node not in arbiters:
             arbiters[node] = NodeCacheArbiter(
                 spaces, _node_budget(node_budgets_mb, node), deferred=True)
+    store = ControllerStore(len(pairs), cfg.history_k)
     return [CaratController(c.client_id, spaces, models, cfg,
-                            arbiter=arbiters[node])
-            for c, node in pairs]
+                            arbiter=arbiters[node], slot=_Slot(store, i))
+            for i, (c, node) in enumerate(pairs)]
 
 
 class CaratPolicy(TuningPolicy):
     """The CARAT co-tuner behind the :class:`TuningPolicy` lifecycle.
 
-    ``step`` keeps the proven fleet engine verbatim: member-ordered
-    ``observe`` over the controller shells, one batched ``decide_many``
-    (vectorized Algorithm 1), per-client ``actuate``, then
-    ``finish_step`` drains every node with a pending stage-2 boundary
-    into one batched Algorithm 2 call.
+    ``step`` runs the fleet engine: observe every shell in member order,
+    one batched ``decide_many`` (vectorized Algorithm 1), per-client
+    ``actuate``, then ``finish_step`` drains every node with a pending
+    stage-2 boundary into one batched Algorithm 2 call. When every bound
+    client is a row of one ``SoACore``, observe is one batched pass over
+    the counter arrays and the shells' rows
+    (:meth:`ControllerStore.probe`); otherwise each shell observes on its
+    own (``CaratController.observe``, the pass's oracle).
     """
 
     name = "carat"
@@ -257,6 +266,13 @@ class CaratPolicy(TuningPolicy):
         self.arbiter_batch_count = 0
         self.node_retune_count = 0
         self.boundary_count = 0     # client-level stage-2 boundary events
+        # every shell's row, at its member position (see _shell_store)
+        self._store: Optional[ControllerStore] = None
+
+    def __getstate__(self) -> Dict[str, Any]:
+        # each pickled shell carries its own row; the next batched pass
+        # gathers them again
+        return dict(self.__dict__, _store=None)
 
     # --------------------------------------------------------- lifecycle
     def bind(self, sim, client_ids: Optional[Sequence[int]] = None) -> None:
@@ -349,12 +365,16 @@ class CaratPolicy(TuningPolicy):
             f"policy {self.name!r}",
             [c.client_id for c in self.controllers], clients)
         rec = _telemetry()
-        pending: List[tuple] = []
         with rec.span("policy.observe", cat="policy"):
-            for ctrl, client in zip(self.controllers, targets):
-                req = ctrl.observe(client, t, dt)
-                if req is not None:
-                    pending.append((ctrl, req[0], req[1]))
+            rows = self._soa_rows(targets)
+            if rows is not None:
+                pending = self._observe_batched(targets, rows, t, dt)
+            else:
+                pending = []
+                for ctrl, client in zip(self.controllers, targets):
+                    req = ctrl.observe(client, t, dt)
+                    if req is not None:
+                        pending.append((ctrl, req[0], req[1]))
         if pending:
             with rec.span("policy.decide", cat="policy"):
                 decisions = self.decide_many(pending)
@@ -363,6 +383,80 @@ class CaratPolicy(TuningPolicy):
                                                             decisions):
                     ctrl.actuate(op, proposal, t, share)
         self.finish_step(t)
+
+    # ------------------------------------------------ batched observe pass
+    def _soa_rows(self, targets: Sequence[IOClient]) -> Optional[np.ndarray]:
+        """The ``SoACore`` rows of the bound clients, in member order, when
+        the batched pass can observe them: every client is a row of one
+        core, every shell observes as ``CaratController`` does, and every
+        stage-2 arbiter defers its retune to ``finish_step`` (an inline
+        retune reads the other members mid-loop). None otherwise."""
+        if not targets or type(targets[0]) is not SoAClientView:
+            return None
+        core = targets[0].core
+        if not all(type(c) is SoAClientView and c.core is core
+                   for c in targets):
+            return None
+        if not all(type(c).observe is STOCK_OBSERVE
+                   and (c.arbiter is None or c.arbiter.deferred)
+                   for c in self.controllers):
+            return None
+        return np.fromiter((c.index for c in targets), np.int64,
+                           len(targets))
+
+    def _shell_store(self) -> ControllerStore:
+        """The store holding every shell's row at its member position,
+        gathered anew when shells were replaced or restored."""
+        st, ctrls = self._store, self.controllers
+        if (st is None or st.n != len(ctrls)
+                or not all(c._slot.store is st and c._slot.row == i
+                           for i, c in enumerate(ctrls))):
+            st = self._store = ControllerStore.gather(
+                [c._slot for c in ctrls])
+        return st
+
+    def _observe_batched(self, targets: Sequence[SoAClientView],
+                         rows: np.ndarray, t: float,
+                         dt: float) -> List[tuple]:
+        """Every shell's observe in one pass over the counter arrays:
+        the same probes, metrics, stage machine and feature rows as the
+        per-shell loop, with the rare per-client actions (stage-2 marks,
+        re-probe resets, bootstrap picks) taken row by row in member
+        order. Returns the ``(ctrl, op, feats)`` the loop would."""
+        ctrls = self.controllers
+        ops, gauges, tunables = targets[0].core.sample_rows(rows)
+        spaces = self.spaces
+        default = (spaces.default_rpc_window, spaces.default_in_flight)
+        pr = self._shell_store().probe(ops, gauges, tunables, t, dt,
+                                       self.cfg, default)
+        for ctrl, client in zip(ctrls, targets):
+            ctrl.client = client
+        for i in np.flatnonzero(pr.boundary):
+            if ctrls[i].arbiter is not None:
+                ctrls[i].arbiter.mark_boundary(ctrls[i])
+        resets = np.flatnonzero(pr.reset)
+        for i in resets:
+            ctrls[i].client.set_rpc_config(*default)
+            ctrls[i].decisions.append((t, "reprobe") + default)
+        boots = np.flatnonzero(pr.bootstrap)
+        for i in boots:
+            # the tau-free greedy pick of CaratController.observe
+            ctrl = ctrls[i]
+            probs = ctrl.tuner._probs(OPS[pr.op[i]], pr.feats[i])
+            w, f = ctrl.spaces.rpc_candidates()[int(np.argmax(probs))]
+            ctrl.client.set_rpc_config(w, f)
+            ctrl.decisions.append((t, "bootstrap", w, f))
+        pending = [(ctrls[i], OPS[pr.op[i]], pr.feats[i])
+                   for i in np.flatnonzero(pr.pending)]
+        rec = _telemetry()
+        if rec.enabled:
+            rec.count("carat.observe_batched", len(ctrls))
+            for name, k in (("carat.reprobe", resets.size),
+                            ("carat.bootstrap", boots.size),
+                            ("carat.probe", len(pending))):
+                if k:
+                    rec.count(name, k)
+        return pending
 
     # ------------------------------------------------------- stage-2 drain
     def _pending_arbiters(self) -> List[NodeCacheArbiter]:
@@ -611,6 +705,11 @@ class CaratPolicy(TuningPolicy):
                 raise KeyError(f"restored shell for unknown client "
                                f"{ctrl.client_id}")
             self.controllers[i] = ctrl
+            if self._store is not None:
+                # the restored shell carries its own row: copy it into
+                # the fleet's store and move the shell there
+                self._store.put([i], ctrl._slot.store, [ctrl._slot.row])
+                ctrl._slot.store, ctrl._slot.row = self._store, i
         # the in-place replacement keeps the same list object, which the
         # id->shell cache keys on — drop it or lookups serve stale shells
         self._shell_cache = None

@@ -77,11 +77,12 @@ class SnapshotBuilder:
         t0 = perf_s()
         cur = stats.snapshot()
         snap: Optional[Snapshot] = None
-        if self._prev is not None:
-            rd = compute_metrics(cur, self._prev, "read", self.interval_s)
-            wr = compute_metrics(cur, self._prev, "write", self.interval_s)
-            d_rd = diff_op(cur.read, self._prev.read)
-            d_wr = diff_op(cur.write, self._prev.write)
+        prev = self._prev
+        if prev is not None:
+            rd = compute_metrics(cur, prev, "read", self.interval_s)
+            wr = compute_metrics(cur, prev, "write", self.interval_s)
+            d_rd = diff_op(cur.read, prev.read)
+            d_wr = diff_op(cur.write, prev.write)
             snap = Snapshot(
                 t=t,
                 read=rd, write=wr,
@@ -112,18 +113,27 @@ class SnapshotBuilder:
         if len(self.history) < 2:
             return None
         cur, prev = self.history[-1], self.history[-2]
-        m_cur = cur.op_metrics(op).vector()
-        m_prev = prev.op_metrics(op).vector()
-        raw = np.concatenate([m_cur, m_prev]).astype(np.float32)
-        feats = normalize_features(raw)
-        deltas = feats[:6] - feats[6:12]
-        cfg = np.array([np.log2(max(cur.window_pages, 1)),
-                        np.log2(max(cur.in_flight, 1))], dtype=np.float32)
-        return np.concatenate([feats, deltas, cfg])
+        return feature_rows(cur.op_metrics(op).vector(),
+                            prev.op_metrics(op).vector(),
+                            cur.window_pages, cur.in_flight)
 
     @property
     def mean_snapshot_time_s(self) -> float:
         return self.snapshot_time_total / max(self.snapshot_count, 1)
+
+
+def feature_rows(m_cur: np.ndarray, m_prev: np.ndarray, window_pages,
+                 in_flight) -> np.ndarray:
+    """H_t from one op direction's metrics at t and t-1 (``(..., 6)``)
+    and the applied config: ``(..., FEATURE_DIM)`` float32. One client's
+    row for :meth:`SnapshotBuilder.feature_vector`, many clients' rows
+    for the fleet's batched observe pass, by the same operations."""
+    raw = np.concatenate([m_cur, m_prev], axis=-1).astype(np.float32)
+    feats = normalize_features(raw)
+    deltas = feats[..., :6] - feats[..., 6:12]
+    cfg = np.log2(np.maximum(np.stack([window_pages, in_flight], axis=-1),
+                             1)).astype(np.float32)
+    return np.concatenate([feats, deltas, cfg], axis=-1)
 
 
 FEATURE_DIM = 20  # 6 metrics x 2 timesteps + 6 deltas + 2 config features

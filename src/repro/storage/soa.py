@@ -793,6 +793,25 @@ class SoACore:
             rpcs_in_flight=int(self.cfg_inflight[i]),
             dirty_cache_mb=int(self.cfg_cache_mb[i]))
 
+    def sample_rows(self, idx: np.ndarray) -> tuple:
+        """Clients ``idx``'s counters at once, in that order: the array
+        twin of :meth:`materialize_stats` for the fleet's batched probe.
+
+        Returns copies: ``(k, 2, len(OP_FIELDS))`` cumulative read and
+        write counters, ``(k, 3)`` gauges (dirty bytes, dirty peak,
+        in-flight peak) and ``(k, 3)`` tunables (RPC window, RPCs in
+        flight, dirty-cache MB).
+        """
+        self.ensure_host()
+        ops = np.stack([np.stack([getattr(o, f)[idx] for f in OP_FIELDS],
+                                 axis=1) for o in (self.read, self.write)],
+                       axis=1)
+        gauges = np.stack([self.dirty_bytes[idx], self.dirty_peak_bytes[idx],
+                           self.inflight_peak[idx]], axis=1)
+        tunables = np.stack([self.cfg_window[idx], self.cfg_inflight[idx],
+                             self.cfg_cache_mb[idx]], axis=1)
+        return ops, gauges, tunables
+
 
 # ---------------------------------------------------------------- views ----
 class _SoAOpView:
