@@ -33,13 +33,26 @@ The numbers compared, each with a limit of its own (``limits/<cell>.json``):
   for a decided client, the default after a re-probe, the best
   candidate of a bootstrap pick, else the one before;
 * ``stage2_mismatch``: clients whose cache limit after the interval is
-  not Algorithm 2's for a node at its stage-2 boundary, or the one
-  before for every other node.
+  not Algorithm 2's for a node at its stage-2 boundary (over all of the
+  node's members, with the budget its arbiter has), or the one before
+  for every other node;
+* ``member_mismatch`` (mixes with phased jobs): clients whose workload
+  in the sampled interval, as the program holds it once its workload
+  phase has run, is not the member the traffic's schedule gives.
+
+The references are the modules the cell's configuration names
+(``fleet_ref`` and ``tuner_ref`` unless it names others). With phased
+jobs the fleet reference steps each client with the member the schedule
+gives at the sampled instant, and the sampler adds to the samples drawn
+from the seed the interval of the first switch it can reach in the
+window and the interval after it, where CARAT re-probes and bootstraps
+the switched clients.
 """
 from __future__ import annotations
 
 import copy
 from dataclasses import dataclass, field
+from types import ModuleType
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -73,6 +86,7 @@ class Sample:
     pending: list = field(default_factory=list)   # (client_id, op)
     proposals: list = field(default_factory=list)
     scored: list = field(default_factory=list)    # (op, H, probs)
+    members: np.ndarray = None     # phased jobs: the program's members
 
 
 class _ScorerSpy:
@@ -139,13 +153,18 @@ class Sampler:
     cells a sample spans two intervals: the first keeps the state and
     configurations the probe of the second differences against."""
 
-    def __init__(self, sim, policy, times: List[float]):
+    def __init__(self, sim, policy, times: List[float], schedule=None,
+                 members: Optional[List[str]] = None):
         self.sim = sim
         self.policy = policy
         self.times = list(times)
         self.samples: List[Sample] = []
         self.current: Optional[Sample] = None
         self._armed: Optional[tuple] = None
+        # phased jobs: the switch interval to sample and the one after
+        self.schedule = schedule
+        self._pair: Optional[List[float]] = None
+        self._member_of = {m: i for i, m in enumerate(members or [])}
         if policy is not None:
             ids = [c.client_id for c in policy.controllers]
             if ids != list(range(len(sim.clients))):
@@ -173,19 +192,40 @@ class Sampler:
     @property
     def open(self) -> bool:
         """A sample is still to be taken."""
-        return bool(self.times) or self._armed is not None
+        return (bool(self.times) or self._armed is not None
+                or bool(self._pair))
+
+    def _at(self, t: float, when: float) -> bool:
+        return abs(t - when) < 0.5 * self.sim.interval_s
 
     def before(self, elapsed: float) -> bool:
         """Take the copies due before the next interval; True when that
         interval is a sampled one."""
+        sim = self.sim
+        t, dt = sim.t, sim.interval_s
+        # a CARAT sample needs the interval before it, the arm
+        lead = dt if self.policy is not None else 0.0
+        if self.schedule is not None and self._pair is None:
+            first = self.schedule.next_switch(t + lead)
+            self._pair = [first, first + dt]
+        pair = self._pair
         if self._armed is None:
-            if not (self.times and elapsed >= self.times[0]):
-                return False
-            self.times.pop(0)
-            if self.policy is not None:
+            if pair and self._at(t, pair[0]):
+                pass                    # the switch interval (no arm)
+            elif pair and lead and self._at(t + lead, pair[0]):
                 self._armed = (self._copy_state(), self._cfg())
                 return False
-        sim = self.sim
+            elif not (self.times and elapsed >= self.times[0]):
+                return False
+            elif pair and t + 2 * lead >= pair[0] - 0.5 * dt:
+                return False            # would overlap the switch pair
+            else:
+                self.times.pop(0)
+                if self.policy is not None:
+                    self._armed = (self._copy_state(), self._cfg())
+                    return False
+        if pair and self._at(t, pair[0]):
+            pair.pop(0)
         cur = Sample(t=sim.t, dt=sim.interval_s,
                      before=self._copy_state(),
                      rng_before=copy.deepcopy(
@@ -206,8 +246,18 @@ class Sampler:
         cur.rng_after = copy.deepcopy(
             self.sim.cluster.rng.gen.bit_generator.state)
         cur.cfg_after = self._cfg()
+        if self.schedule is not None:
+            # each client's workload once the workload phase has run
+            specs = self.sim.core.specs
+            cur.members = np.fromiter(
+                (self._member_of.get(w.name, -1) for w in specs), np.int64,
+                len(specs))
         self.samples.append(cur)
         self.current = None
+        if (self.policy is not None and self._pair
+                and self._at(cur.t + cur.dt, self._pair[0])):
+            # the next interval is sampled too: this one is its arm
+            self._armed = (cur.before, cur.cfg_before)
 
     def _cfg(self):
         core = self.sim.core
@@ -251,16 +301,28 @@ class Reference:
     policy: Dict
     clients_per_node: int = 1
     models: Dict[str, dict] = field(default_factory=dict)
+    schedule: object = None        # traffic.Schedule of phased jobs
+    fleet: ModuleType = fleet_ref  # the references the cell names
+    tuner: ModuleType = tuner_ref
 
 
 def fleet_numbers(ref: Reference, samples: List[Sample],
                   dtype=np.float64, use_program: bool = True) -> Dict:
-    """``fleet_rel_err`` and ``noise_draws`` over the samples. With
-    ``use_program=False`` the reference at ``dtype`` stands in for the
-    program (the control)."""
+    """``fleet_rel_err`` and ``noise_draws`` over the samples (and, with
+    phased jobs, ``member_mismatch``). With ``use_program=False`` the
+    reference at ``dtype`` stands in for the program (the control)."""
+    fleet_ref = ref.fleet
     wl = fleet_ref.member_arrays(ref.members, ref.member_idx)
     worst, where, draws = 0.0, "", 0
+    members, switched = 0, 0
     for s in samples:
+        if ref.schedule is not None:
+            idx = ref.schedule.member_at(s.t)
+            wl = fleet_ref.member_arrays(ref.members, idx)
+            switched += int(np.count_nonzero(
+                idx != ref.schedule.member_at(s.t - s.dt)))
+            if use_program:
+                members += int(np.count_nonzero(s.members != idx))
         st64 = fleet_ref.statics(ref.pfs, wl, *s.cfg_before, ref.offsets)
         act = fleet_ref.duty_active(st64, s.t)
         mask = fleet_ref.ost_active(st64, np.asarray(s.before["dirty"]),
@@ -278,8 +340,11 @@ def fleet_numbers(ref: Reference, samples: List[Sample],
         err, name = fleet_ref.rel_error(s.before, want, got)
         if err > worst or not where:
             worst, where = err, name
-    return {"fleet_rel_err": worst, "noise_draws": draws,
-            "_fleet_worst_field": where}
+    out = {"fleet_rel_err": worst, "noise_draws": draws,
+           "_fleet_worst_field": where}
+    if ref.schedule is not None:
+        out.update(member_mismatch=members, _switched=switched)
+    return out
 
 
 def tuner_numbers(ref: Reference, samples: List[Sample],
@@ -288,15 +353,16 @@ def tuner_numbers(ref: Reference, samples: List[Sample],
     With ``use_program=False`` the reference one precision lower stands
     in for the program (the control): its feature rows rounded to
     bfloat16 and its probabilities from the bfloat16 GBDT."""
+    tuner_ref = ref.tuner
     pol = ref.policy
-    if int(ref.clients_per_node) != 1:
-        raise ValueError("the stage-2 reference gives each client a node "
-                         "of its own")
+    per_node = int(ref.clients_per_node)
     th = tuner_ref.theta(pol["rpc_window_pages"], pol["rpcs_in_flight"])
     cands = [(w, f) for w in pol["rpc_window_pages"]
              for f in pol["rpcs_in_flight"]]
     grid = pol["dirty_cache_mb"]
-    budget = pol["node_budget_share"] * grid[-1]
+    # a node's stage-2 budget, as NodeCacheArbiter.budget() gives it:
+    # node_budget_share x the largest cache limit x the node's members
+    share = pol["node_budget_share"] * grid[-1]
     default = (pol["defaults"]["default_rpc_window"],
                pol["defaults"]["default_in_flight"])
     dp, feat_err = 0.0, 0.0
@@ -356,11 +422,13 @@ def tuner_numbers(ref: Reference, samples: List[Sample],
                     ew[cid], ef[cid] = chosen
         boot = np.nonzero(obs["bootstrap"])[0]
         boots += boot.size
-        for i in np.nonzero(obs["boundary"])[0]:
-            ec[i] = tuner_ref.algorithm2(
-                [obs["saw"][i]], [obs["peak_cache"][i]],
-                [obs["peak_inflight"][i]], [obs["write_rpcs"][i]],
-                budget, grid)[0]
+        # node j holds clients jk ... jk+k-1; a boundary of any member
+        # re-allocates the whole node
+        for j in np.unique(np.nonzero(obs["boundary"])[0] // per_node):
+            m = slice(j * per_node, (j + 1) * per_node)
+            ec[m] = tuner_ref.algorithm2(
+                obs["saw"][m], obs["peak_cache"][m], obs["peak_inflight"][m],
+                obs["write_rpcs"][m], share * len(ec[m]), grid)
             nodes += 1
         if not use_program:
             continue

@@ -72,10 +72,69 @@ def _devices(chips: int, require_tpu: bool):
     return devs
 
 
-def _workloads(cell: spec.Cell, inputs):
+class _Switches:
+    """The switch instants of a schedule, without end, as the sequence
+    of boundaries ``SchedulePolicy`` walks."""
+
+    def __init__(self, sched: traffic.Schedule):
+        self._sched = sched
+
+    def __len__(self) -> int:
+        return sys.maxsize
+
+    def __getitem__(self, i: int) -> float:
+        return self._sched.switch(i + 1)
+
+
+class _JobSchedule:
+    """One job's phases for ``SchedulePolicy``: the member its schedule
+    gives at each instant, repeating for as long as the run lasts."""
+
+    def __init__(self, sched: traffic.Schedule, client: int, specs):
+        self._sched, self._client, self._specs = sched, client, specs
+        self.boundaries = _Switches(sched)
+
+    def spec_at(self, t: float):
+        return self._specs[self._sched.member_at(t, self._client)]
+
+
+def build(cell: spec.Cell, n: int, seed: int):
+    """The fleet of one run, before any policy: the simulation, the
+    traffic's draws and, for a mix with phased jobs, their schedule
+    (driven by one ``SchedulePolicy``). With ``clients_per_node`` k > 1,
+    node j holds clients jk ... jk+k-1."""
+    from repro.storage import Simulation
+    from repro.storage.client import ClientConfig
+    from repro.storage.params import PFSParams
+    from repro.storage.sim import SchedulePolicy
     from repro.storage.workloads import WorkloadSpec
-    members = [WorkloadSpec(**m) for m in cell.traffic["members"]]
-    return [members[i] for i in inputs.member_idx]
+    cfg = cell.config
+    pfs = dict(cfg["pfs"], n_osts=int(cfg["n_osts"]))
+    members = cell.traffic["members"]
+    specs = [WorkloadSpec(**m) for m in members]
+    inputs = traffic.generate(n, int(pfs["n_osts"]), len(members), seed)
+    sched = None
+    first = inputs.member_idx
+    if "schedule" in cell.traffic:
+        sched = traffic.schedule(n, cell.traffic["schedule"],
+                                 [m["name"] for m in members], seed)
+        first = sched.member_at(0.0)
+    k = int(cfg["clients_per_node"])
+    sim = Simulation([specs[i] for i in first], params=PFSParams(**pfs),
+                     configs=[ClientConfig(**cfg["client_defaults"])] * n,
+                     seed=inputs.sim_seed, backend="soa-jax",
+                     stripe_offsets=inputs.stripe_offsets.tolist(),
+                     topology=[i // k for i in range(n)] if k > 1 else None,
+                     interval_s=float(cell.traffic["interval_s"]))
+    if sched is not None:
+        # one schedule object per job: its clients share it
+        jobs = {}
+        for i, j in enumerate(sched.job_of.tolist()):
+            if j not in jobs:
+                jobs[j] = _JobSchedule(sched, i, specs)
+        sim.attach_policy(SchedulePolicy(
+            {i: jobs[j] for i, j in enumerate(sched.job_of.tolist())}))
+    return sim, inputs, sched
 
 
 def _policy(cell: spec.Cell, sim):
@@ -220,22 +279,15 @@ def _run(cell, seed, seconds, trace, t_start, n_clients, log, keep, devs,
     dev = devs[0]
     phases = {"imports": time.perf_counter() - t_start}
 
-    from repro.storage import Simulation
-    from repro.storage.client import ClientConfig
-    from repro.storage.params import PFSParams
     cfg = cell.config
     n = int(n_clients or cfg["n_clients"])
     pfs = dict(cfg["pfs"], n_osts=int(cfg["n_osts"]))
     members = cell.traffic["members"]
-    inputs = traffic.generate(n, int(pfs["n_osts"]), len(members), seed)
-    sim = Simulation(_workloads(cell, inputs), params=PFSParams(**pfs),
-                     configs=[ClientConfig(**cfg["client_defaults"])] * n,
-                     seed=inputs.sim_seed, backend="soa-jax",
-                     stripe_offsets=inputs.stripe_offsets.tolist(),
-                     interval_s=float(cell.traffic["interval_s"]))
+    sim, inputs, sched = build(cell, n, seed)
     policy = _policy(cell, sim)
     sampler = check.Sampler(sim, policy, check.sample_times(
-        seed, seconds, int(cell.traffic["samples"])))
+        seed, seconds, int(cell.traffic["samples"])), schedule=sched,
+        members=[m["name"] for m in members])
     if trace:
         _annotate(sim, policy)
     phases["build"] = time.perf_counter() - t_start - phases["imports"]
@@ -325,11 +377,11 @@ def _run(cell, seed, seconds, trace, t_start, n_clients, log, keep, devs,
                           member_idx=inputs.member_idx,
                           offsets=inputs.stripe_offsets,
                           policy=cell.traffic["policy"],
-                          clients_per_node=int(cfg["clients_per_node"]))
+                          clients_per_node=int(cfg["clients_per_node"]),
+                          schedule=sched, **cell.references)
     numbers = check.fleet_numbers(ref, sampler.samples)
     if is_carat:
-        from chipbench import tuner_ref
-        ref.models = {op: tuner_ref.load_model(os.path.join(
+        ref.models = {op: ref.tuner.load_model(os.path.join(
             cell.bench_dir, "models", f"gbdt_{op}.npz"))
             for op in ("read", "write")}
         numbers.update(check.tuner_numbers(ref, sampler.samples))
@@ -399,7 +451,8 @@ def _run(cell, seed, seconds, trace, t_start, n_clients, log, keep, devs,
             "decisions_compared": numbers.get("_decisions_compared"),
             "stage2_nodes_compared": numbers.get("_stage2_nodes_compared"),
             "bootstraps": numbers.get("_bootstraps"),
-            "resets": numbers.get("_resets")}
+            "resets": numbers.get("_resets"),
+            "switched": numbers.get("_switched")}
     print("chipbench: " + ", ".join(f"{k}={v}" for k, v in info.items()),
           file=log)
     for name, c in checks.items():

@@ -6,7 +6,8 @@ short window (the samples it compares are the same as a full run's), and
 reads every compared number twice: from the program, and from the
 control, which puts the reference computed one precision lower in the
 program's place (float32 for the float64 fleet step, bfloat16 for the
-float32 GBDT kernel). The limits in ``limits/<cell>.json`` lie between
+float32 GBDT kernel), both through the references that the cell's
+configuration names. The limits in ``limits/<cell>.json`` lie between
 the program's largest reading and the control's smallest. The
 benchmark's own runs never run the control.
 
@@ -55,10 +56,12 @@ def main(argv=None) -> int:
         prog = {k: v for k, v in keep["numbers"].items()
                 if not k.startswith("_")}
         ctrl = control_numbers(keep, cell.is_carat)
+        counts = {k[1:]: v for k, v in keep["numbers"].items()
+                  if k.startswith("_") and k != "_fleet_worst_field"}
         rows.append({"seed": seed, "program": prog, "control": ctrl,
                      "correct": res["correct"],
                      "worst_field": keep["numbers"].get("_fleet_worst_field"),
-                     "samples": len(keep["samples"])})
+                     "samples": len(keep["samples"]), "compared": counts})
         print(json.dumps(rows[-1]), flush=True)
         del keep, res
         gc.collect()            # frees the last fleet's device buffers

@@ -1,7 +1,9 @@
 """Helpers of the benchmark's CPU self-tests: one run of a cell at a
 small size on the CPU (the chip check skipped), and faults planted in
 the timed path underneath it."""
+import dataclasses
 import io
+import json
 import os
 import sys
 import time
@@ -20,8 +22,26 @@ N_CLIENTS = 128
 SECONDS = 1.5
 
 
-def run_small(cell_name, seed=20261016, keep=None, trace=False):
-    cell = spec.load_cell(cell_name)
+def unlisted_cell(name, config, traffic, like):
+    """A cell that ``BENCHMARK.json`` does not list, from its files: its
+    configuration and traffic, with the limits (and, for phased jobs,
+    ``member_mismatch`` 0) and metrics of the listed cell ``like``."""
+    base = spec.load_cell(like)
+    with open(os.path.join(BENCH, "configs", config + ".json")) as f:
+        cfg = json.load(f)
+    t = spec.load_traffic(traffic)
+    numbers = dict(base.limits["numbers"])
+    if "schedule" in t:
+        numbers["member_mismatch"] = 0
+    return dataclasses.replace(
+        base, name=name, config=cfg, traffic=t, limits={"numbers": numbers},
+        references=spec.load_references(cfg))
+
+
+def run_small(cell, seed=20261016, keep=None, trace=False):
+    """One small run of ``cell``, a cell's name or a loaded cell."""
+    if isinstance(cell, str):
+        cell = spec.load_cell(cell)
     return harness.run(cell, seed, SECONDS, trace, time.perf_counter(),
                        require_tpu=False, n_clients=N_CLIENTS,
                        log=io.StringIO(), keep=keep, compile_cache=False)
@@ -120,11 +140,12 @@ def alter_features(monkeypatch):
     monkeypatch.setattr(CaratController, "observe", patched)
 
 
-def control_checks(cell_name, keep):
+def control_checks(cell, keep):
     """The control's numbers, put in the program's place, judged by the
     cell's limits."""
-    from chipbench import check, spec
-    cell = spec.load_cell(cell_name)
+    from chipbench import check
+    if isinstance(cell, str):
+        cell = spec.load_cell(cell)
     nums = check.fleet_numbers(keep["ref"], keep["samples"],
                                dtype=np.float32, use_program=False)
     if cell.is_carat:

@@ -46,6 +46,15 @@ def test_config_file_names_its_cuts(entry):
     assert set(entry["reduced"]) == set(cfg["reduced"])
     for key in entry["reduced"]:
         assert key in cfg
+    # the optional keys: shared nodes say where k comes from, and each
+    # named reference is a module of the benchmark's own
+    assert cfg["clients_per_node"] >= 1
+    if cfg["clients_per_node"] > 1:
+        assert cfg["assumed"].get("clients_per_node")
+    assert set(cfg.get("references", {})) <= set(spec.REFERENCE_ROLES)
+    for module in cfg.get("references", {}).values():
+        assert os.path.exists(os.path.join(BENCH, "chipbench",
+                                           module + ".py"))
 
 
 @pytest.mark.parametrize("name", METRICS)
@@ -77,6 +86,85 @@ def test_missing_pieces_are_errors():
         spec.load_cell("no_such_config.no_such_mix")
     with pytest.raises(spec.SpecError):
         spec.load_reader("no_such_metric")
+    with pytest.raises(spec.SpecError):
+        spec.load_references({"references": {"fleet": "no_such_ref"}})
+    with pytest.raises(spec.SpecError):
+        spec.load_references({"references": {"planner": "fleet_ref"}})
+
+
+@pytest.mark.parametrize("bad", [
+    {"segment_s": 0.0}, {"start": "free_offset"}, {"job_clients": 0},
+    {"sequences": {"read_seq": ["no_such_member"]}}, {"extra": 1}])
+def test_a_malformed_schedule_is_an_error(bad):
+    mix = {"name": "m", "members": [{"name": "a"}], "schedule": dict(
+        {"sequences": {"q": ["a"]}, "segment_s": 20.0, "job_clients": 4,
+         "start": "whole_segment"}, **bad)}
+    with pytest.raises(spec.SpecError):
+        spec._check_schedule(mix)
+
+
+# The cells that run the plain path: no shared nodes, no phased jobs, the
+# stock references, and the traffic draws they had before these keys
+# existed (digests of generate()'s arrays for fixed seeds).
+PLAIN_CELLS = ["frontier_9408.carat_striped", "frontier_9408.static_striped",
+               "fugaku_158976.static_striped"]
+DRAWS = [
+    (9408, 448, 2**31 + 7,
+     "fdaec66c7f0428dc809892314901645adc590b641d45579db3c1f8096dabd46d"),
+    (79488, 224, 2147480005,
+     "903527b38e9b6a4d8a1defa8c0c025e6fffdfe09b3cc30ff9a3f5ebbadc2780f"),
+    (128, 448, 20261016,
+     "3a4f84eca36153cf80a449628dbaf9928ff5bcda6304a0f92572d1cd00ddfb22"),
+]
+
+
+@pytest.mark.parametrize("name", PLAIN_CELLS)
+def test_plain_cells_build_the_plain_fleet(name):
+    from chipbench import fleet_ref, harness, tuner_ref
+    cell = spec.load_cell(name)
+    assert cell.references == {"fleet": fleet_ref, "tuner": tuner_ref}
+    sim, inputs, sched = harness.build(cell, 64, 7)
+    assert sched is None and sim.topology is None
+    assert sim.policies() == []
+    members = [m["name"] for m in cell.traffic["members"]]
+    assert [c.workload.name for c in sim.clients] == \
+        [members[i] for i in inputs.member_idx]
+
+
+@pytest.mark.parametrize("n,n_osts,seed,digest", DRAWS)
+def test_traffic_draws_are_unchanged(n, n_osts, seed, digest):
+    import hashlib
+    x = traffic.generate(n, n_osts, 8, seed)
+    got = hashlib.sha256(x.member_idx.tobytes() + x.stripe_offsets.tobytes()
+                         + str(x.sim_seed).encode()).hexdigest()
+    assert got == digest
+
+
+def test_schedule_gives_equal_shares_and_whole_segment_switches():
+    mix = spec.load_traffic("carat_fig7")
+    plan, names = mix["schedule"], [m["name"] for m in mix["members"]]
+    a = traffic.schedule(9408, plan, names, 2**31 + 11)
+    b = traffic.schedule(9408, plan, names, 2**31 + 11)
+    c = traffic.schedule(9408, plan, names, 12)
+    assert np.array_equal(a.client_start, b.client_start)
+    assert np.array_equal(a.client_seq, b.client_seq)
+    assert not np.array_equal(a.client_seq, c.client_seq)
+    for x in (a, c):
+        # 73 jobs of 128 and one of 64; 25/25/24 jobs per sequence
+        jobs = np.bincount(x.job_of)
+        assert jobs.size == 74 and set(jobs[:-1]) == {128} and jobs[-1] == 64
+        job_seq = x.client_seq[np.searchsorted(x.job_of, np.arange(74))]
+        assert np.bincount(job_seq).tolist() == [25, 25, 24]
+        job_start = x.client_start[np.searchsorted(x.job_of, np.arange(74))]
+        for q in range(3):
+            shares = np.bincount(job_start[job_seq == q], minlength=4)
+            assert shares.max() - shares.min() <= 1
+    # members change only at the multiples of segment_s, every job at once
+    seg = plan["segment_s"]
+    assert np.array_equal(a.member_at(0.0), a.member_at(seg - 0.5))
+    assert np.all(a.member_at(seg) != a.member_at(seg - 0.5))
+    assert np.array_equal(a.member_at(4 * seg), a.member_at(0.0))
+    assert a.next_switch(80.5) == 100.0 and a.next_switch(100.0) == 100.0
 
 
 def test_peaks_table_is_keyed_by_device_kind():
