@@ -211,7 +211,7 @@ def _check_kernel_compiled(packed, n_rows: int) -> None:
 
 
 def phase_kernel(models: dict, policy) -> None:
-    from repro.kernels.gbdt_infer.ops import GridGBDTScorer
+    from repro.kernels.gbdt_infer.ops import GridGBDTScorer, bucket_clients
     theta = policy.spaces.theta_features()
     for op, model in models.items():
         feats = [c.builder.feature_vector(op) for c in policy.controllers]
@@ -230,12 +230,12 @@ def phase_kernel(models: dict, policy) -> None:
         diff = float(np.max(np.abs(got - want)))
         rows = len(H) * len(theta)
         log("kernel", f"{op}: {rows} rows, first call (compile + run) "
-                      f"{first:.3f} s, second call {run:.3f} s (host "
-                      f"cross-product + transfer + kernel), max |dp| vs "
-                      f"numpy {diff!r}")
+                      f"{first:.3f} s, second call {run:.3f} s (client "
+                      f"rows up, device-built rows + kernel, pull), max "
+                      f"|dp| vs numpy {diff!r}")
         check(diff <= PROB_ATOL, f"{op}: Pallas vs numpy max |dp| {diff!r} "
                                  f"> {PROB_ATOL}")
-        _check_kernel_compiled(pal.packed, -(-rows // 128) * 128)
+        _check_kernel_compiled(pal.packed, bucket_clients(len(H)) * len(theta))
 
 
 def four_chips(devs, seed: int) -> None:

@@ -12,15 +12,19 @@ size.
 The packed tensors stay NumPy and move to the device only inside a call,
 so building or pickling a scorer (and the policy that holds it) touches
 no device — a worker process that unpickles a policy never reaches for a
-chip.
+chip. :class:`GridGBDTScorer` keeps device copies of its grid and tables
+after its first kernel call, and drops them when pickled.
 
 :class:`GridGBDTScorer` is the fleet-tuning entry point: it scores a
 whole node's clients against the static candidate grid in one call,
 factorizing the split comparisons so the per-client cost falls with
-batch size (see the class docstring).
+batch size (see the class docstring). On the kernel path it uploads only
+the client rows, padded to a bucketed count (:func:`bucket_clients`),
+and builds the client × candidate rows on the device.
 """
 from __future__ import annotations
 
+import functools
 from collections import Counter
 from dataclasses import dataclass
 from typing import Literal, Optional
@@ -30,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.ml.gbdt import ObliviousGBDT, _sigmoid
+from repro.core.runtime.telemetry.recorder import active as _telemetry
 from repro.kernels.gbdt_infer.kernel import gbdt_logits_pallas
 from repro.kernels.gbdt_infer.ref import gbdt_logits_ref
 
@@ -57,6 +62,39 @@ def resolve_backend(backend: Backend, n_rows: int) -> str:
 
 def _round_up(v: int, m: int) -> int:
     return ((v + m - 1) // m) * m
+
+
+def bucket_clients(n: int) -> int:
+    """Client count a kernel batch of ``n`` clients is padded to.
+
+    ``n`` rounded up to a multiple of ``max(128, 2**(floor(log2 n) - 3))``:
+    under 1/8 padding from 1,024 clients up, at most 8 counts per octave,
+    and every count a multiple of 128, so its rows fill whole kernel
+    blocks whatever the grid's size. The kernel then compiles once per
+    bucket, not once per decided split.
+    """
+    n = max(int(n), 1)
+    return _round_up(n, max(128, 1 << max(n.bit_length() - 4, 0)))
+
+
+@functools.partial(jax.jit, static_argnames=("f_pad",))
+def _cross_rows(h, theta, *, f_pad: int):
+    """Kernel rows of the client × candidate product, built on the device:
+    row ``i * c + j`` is ``[h[i] | theta[j]]``, zero-padded to ``f_pad``
+    columns.
+
+    Each row is gathered from the column-padded halves and merged by a
+    select, so every value is copied, never computed. (``h`` broadcast
+    and reshaped to rows is the same program, but XLA:TPU's relayout of
+    it compiles for about a minute at 5,120 clients.)
+    """
+    (n, n_h), c = h.shape, theta.shape[0]
+    h_p = jnp.pad(h, ((0, 0), (0, f_pad - n_h)))
+    t_p = jnp.pad(theta, ((0, 0), (n_h, f_pad - n_h - theta.shape[1])))
+    r = jnp.arange(n * c, dtype=jnp.int32)
+    return jnp.where(jnp.arange(f_pad) < n_h,
+                     jnp.take(h_p, r // c, axis=0, mode="clip"),
+                     jnp.take(t_p, r % c, axis=0, mode="clip"))
 
 
 @dataclass(frozen=True)
@@ -170,7 +208,18 @@ class GridGBDTScorer:
     tensors (float32-tolerance agreement, used on accelerators).
 
     ``calls`` counts scored batches by ``(resolved backend, kernel rows)``
-    — what "auto" chose for each batch size.
+    — what "auto" chose for each batch size; the rows are the problem's,
+    ``n_clients * n_cand``, without padding.
+
+    Backend "pallas" uploads only ``H``, zero-padded to
+    :func:`bucket_clients` rows, builds the cross-product rows on the
+    device (:func:`_cross_rows`: the same float32 values in the same
+    order as the host cross product), runs the kernel and the sigmoid
+    there, and pulls one float32 probability per row. The grid and the
+    kernel tables go to the device on the first such call. While a
+    telemetry recorder is on it counts ``gbdt.pad_rows`` (kernel rows
+    added by the bucketing) and ``gbdt.new_shape`` (calls at a bucket
+    this scorer had not dispatched before: the kernel's compiles).
     """
 
     def __init__(self, model: ObliviousGBDT, theta: np.ndarray,
@@ -185,6 +234,8 @@ class GridGBDTScorer:
         self.cand_chunk = max(int(cand_chunk), 1)
         self.calls: Counter = Counter()
         self._buffers: dict = {}       # (n, chunk) -> (int32 idx, f32 gather)
+        self._dev: Optional[tuple] = None   # device grid + kernel tables
+        self._buckets: set = set()          # client buckets dispatched
         self.packed = pack_gbdt(model)
         n_h = model.n_features - self.theta.shape[1]
         if n_h <= 0:
@@ -211,6 +262,11 @@ class GridGBDTScorer:
         self._idx_theta_flat = idx_t + tree_base          # (n_cand, T)
         self._leaf_flat = model.leaf.ravel()
 
+    def __getstate__(self) -> dict:
+        # device copies stay in their process; an unpickled scorer
+        # uploads (and compiles) afresh
+        return {**self.__dict__, "_dev": None, "_buckets": set()}
+
     @property
     def n_candidates(self) -> int:
         return self.theta.shape[0]
@@ -227,6 +283,8 @@ class GridGBDTScorer:
         self.calls[(be, rows)] += 1
         if be == "numpy":
             return self._predict_numpy(H)
+        if be == "pallas":
+            return self._predict_pallas(H)
         return self._predict_packed(H, be)
 
     # ------------------------------------------------------------ backends
@@ -268,3 +326,26 @@ class GridGBDTScorer:
                                    block_n=self.block_n)
         return np.asarray(probs, dtype=np.float64).reshape(n, c)
 
+    def _predict_pallas(self, H: np.ndarray) -> np.ndarray:
+        n, c = H.shape[0], self.n_candidates
+        if self._dev is None:
+            p = self.packed
+            self._dev = tuple(jnp.asarray(a) for a in
+                              (self.theta, p.sel, p.thr, p.leaf_t, p.base))
+        theta, sel, thr, leaf_t, base = self._dev
+        # rounding to block_n is a no-op at the default 128: n_pad * c
+        # rows fill whole kernel blocks
+        n_pad = _round_up(bucket_clients(n), self.block_n)
+        H_pad = np.zeros((n_pad, self.n_h), dtype=np.float32)
+        H_pad[:n] = H
+        x = _cross_rows(H_pad, theta, f_pad=self.packed.f_pad)
+        logits = gbdt_logits_pallas(x, sel, thr, leaf_t, base,
+                                    block_n=self.block_n)
+        probs = np.asarray(jax.nn.sigmoid(logits))[:n * c]
+        rec = _telemetry()
+        if rec.enabled:
+            rec.count("gbdt.pad_rows", x.shape[0] - n * c)
+            if n_pad not in self._buckets:
+                rec.count("gbdt.new_shape")
+        self._buckets.add(n_pad)
+        return probs.astype(np.float64).reshape(n, c)

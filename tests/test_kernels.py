@@ -10,7 +10,9 @@ from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import (flash_attention_ref,
                                                flash_attention_xla_chunked)
-from repro.kernels.gbdt_infer.ops import PallasGBDTScorer, gbdt_predict_proba, pack_gbdt
+from repro.kernels.gbdt_infer.ops import (GridGBDTScorer, PallasGBDTScorer,
+                                          bucket_clients, gbdt_predict_proba,
+                                          pack_gbdt)
 
 
 # ----------------------------------------------------------- flash attention
@@ -152,3 +154,107 @@ def test_gbdt_learns(trained_gbdt):
     model, X, y = trained_gbdt
     acc = (model.predict(X) == y).mean()
     assert acc > 0.9
+
+
+# ------------------------------------------- grid scorer, device-built rows
+def _host_cross_product(scorer, H, backend):
+    """The scorer's kernel rows built on the host, scored through
+    ``gbdt_predict_proba``: the path the device-built rows replace."""
+    n, c = H.shape[0], scorer.n_candidates
+    X = np.concatenate([np.repeat(H, c, axis=0),
+                        np.tile(scorer.theta, (n, 1))], axis=1)
+    return np.asarray(gbdt_predict_proba(scorer.packed, X, backend=backend),
+                      dtype=np.float64).reshape(n, c)
+
+
+@pytest.fixture(scope="module")
+def grid_theta():
+    from repro.core import default_spaces
+    return default_spaces().theta_features()
+
+
+@pytest.mark.parametrize("x64", [False, True], ids=["x32", "x64"])
+@pytest.mark.parametrize("n", [3, 127, 128, 129, 700, 1025])
+@pytest.mark.parametrize("op", ["read", "write"])
+def test_grid_scorer_device_rows_bit_identical(tiny_models, grid_theta, op,
+                                               n, x64):
+    """Backend "pallas" builds the cross product on the device at a
+    bucketed client count and returns exactly the probabilities of the
+    host cross product, unpadded; ``calls`` counts the problem's rows."""
+    scorer = GridGBDTScorer(tiny_models[op], grid_theta, backend="pallas")
+    H = np.random.default_rng(n).normal(size=(n, scorer.n_h)).astype(
+        np.float32)
+    with jax.enable_x64(x64):
+        got = scorer(H)
+        want = _host_cross_product(scorer, H, "pallas")
+    assert got.dtype == np.float64 and got.shape == (n, len(grid_theta))
+    assert np.array_equal(got, want)
+    assert dict(scorer.calls) == {("pallas", n * len(grid_theta)): 1}
+
+
+@pytest.mark.parametrize("n", [1, 2])
+def test_grid_scorer_small_batches_stay_jnp(tiny_models, grid_theta,
+                                            monkeypatch, n):
+    """Below ``_PALLAS_MIN_ROWS`` kernel rows, "auto" on a TPU still runs
+    the jnp oracle over the host cross product, unchanged."""
+    from repro.kernels.gbdt_infer import ops
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    scorer = GridGBDTScorer(tiny_models["read"], grid_theta, backend="auto")
+    H = np.random.default_rng(n).normal(size=(n, scorer.n_h)).astype(
+        np.float32)
+    got = scorer(H)
+    assert dict(scorer.calls) == {("jnp", n * len(grid_theta)): 1}
+    assert np.array_equal(got, _host_cross_product(scorer, H, "jnp"))
+    assert scorer._dev is None
+
+
+def test_bucket_clients_rule():
+    """Every bucket holds its count, fills whole 128-row kernel blocks,
+    wastes under 1/8 from 1,024 clients up, and an octave of bucket sizes
+    holds at most 8."""
+    per_octave = {}
+    for n in range(1, 1 << 17):
+        b = bucket_clients(n)
+        assert b >= n and b % 128 == 0, n
+        if n >= 1024:
+            assert b - n < n / 8, n
+        per_octave.setdefault(b.bit_length(), set()).add(b)
+    assert max(len(v) for v in per_octave.values()) <= 8
+    assert bucket_clients(4704) == 5120
+
+
+@pytest.mark.parametrize("lo,hi", [(3213, 3508), (5900, 6195)])
+def test_grid_scorer_split_sweep_meets_two_buckets(tiny_models, grid_theta,
+                                                   lo, hi):
+    """The Fig 7 cell's decided splits move by tens of clients between
+    intervals; over each op's whole range the kernel meets at most two
+    client buckets (``gbdt.new_shape``), and ``gbdt.pad_rows`` counts the
+    padding. One candidate keeps the interpreted kernel short."""
+    from repro.core.runtime.telemetry import recorder
+    scorer = GridGBDTScorer(tiny_models["write"], grid_theta[:1],
+                            backend="pallas")
+    H = np.random.default_rng(lo).normal(size=(hi, scorer.n_h)).astype(
+        np.float32)
+    with recorder.enabled() as rec:
+        for n in range(lo, hi + 1):
+            scorer(H[:n])
+        counters = rec.snapshot()["counters"]
+    assert 1 <= counters["gbdt.new_shape"] <= 2
+    assert counters["gbdt.pad_rows"] == sum(
+        bucket_clients(n) - n for n in range(lo, hi + 1))
+    assert sum(scorer.calls.values()) == hi - lo + 1
+
+
+def test_grid_scorer_pickles_without_device_arrays(tiny_models, grid_theta):
+    """Device copies made by a kernel call are not pickled: the unpickled
+    scorer holds no device array and scores the same."""
+    import pickle
+    scorer = GridGBDTScorer(tiny_models["read"], grid_theta, backend="pallas")
+    H = np.random.default_rng(7).normal(size=(200, scorer.n_h)).astype(
+        np.float32)
+    want = scorer(H)
+    assert scorer._dev is not None
+    clone = pickle.loads(pickle.dumps(scorer))
+    assert clone._dev is None and not clone._buckets
+    assert not any(isinstance(v, jax.Array) for v in vars(clone).values())
+    assert np.array_equal(clone(H), want)

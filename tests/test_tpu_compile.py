@@ -78,6 +78,23 @@ def test_gbdt_kernel_compiles_for_v5e(one_chip, x64):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("x64", [False, True], ids=["x32", "x64"])
+def test_gbdt_cross_rows_compile_for_v5e(one_chip, x64):
+    """The grid scorer's device-built kernel rows at the CARAT cell's
+    bucket (5,120 clients x 63 candidates, 24 padded features)."""
+    from repro.kernels.gbdt_infer.ops import _cross_rows, bucket_clients
+    n_pad = bucket_clients(4704)
+
+    def spec(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    with jax.enable_x64(x64):
+        compiled = _cross_rows.lower(spec((n_pad, 20)), spec((63, 2)),
+                                     f_pad=24).compile()
+    out = compiled.out_info
+    assert out.shape == (n_pad * 63, 24) and out.dtype == jnp.float32
+
+
 def test_fused_fleet_step_compiles_for_v5e(one_chip):
     """The fused plan+resolve+commit step of ``DeviceFleet`` over the
     striped mix, widened from a 16-client fleet's pytrees to 4,096
