@@ -19,7 +19,7 @@ from repro.storage.client import IOClient, ClientConfig
 from repro.storage.pfs import ClusterFeedback, PFSCluster
 from repro.storage.sim import SchedulePolicy, Simulation, SimResult
 from repro.storage.soa import (DemandBatch, PlanBatch, SoAClientView,
-                               SoACore, resolve_xp)
+                               SoACore)
 from repro.storage.replay import (Trace, TraceRecord, WorkloadSchedule,
                                   SchedulePhase, parse_trace, render_trace,
                                   load_trace, bundled_traces,
@@ -32,7 +32,7 @@ __all__ = [
     "PFSParams", "PAGE_SIZE", "WorkloadSpec", "WORKLOADS", "get_workload",
     "idle_workload", "IOClient", "ClientConfig", "PFSCluster",
     "ClusterFeedback", "Simulation", "SimResult", "SchedulePolicy",
-    "SoACore", "SoAClientView", "PlanBatch", "DemandBatch", "resolve_xp",
+    "SoACore", "SoAClientView", "PlanBatch", "DemandBatch",
     "Trace", "TraceRecord", "WorkloadSchedule", "SchedulePhase",
     "parse_trace", "render_trace", "load_trace", "bundled_traces",
     "load_bundled_trace", "compile_trace", "segment_phases",

@@ -1,10 +1,9 @@
 """Device-resident fleet stepping for the ``soa-jax`` backend.
 
-:class:`~repro.storage.soa.SoACore` with ``xp="jax"`` runs its
-elementwise plan/commit math through ``jnp`` but keeps every carried
-array host-side, round-tripping the whole fleet state twice per
-interval and serializing in the cluster resolve. This module closes
-that gap:
+:class:`~repro.storage.soa.SoACore` holds the fleet as NumPy arrays and
+plans and commits it on the host, bit-identical to the scalar oracle.
+``soa-jax`` keeps that core as the host-side copy and steps whole
+intervals on a device instead:
 
 * :class:`DeviceFleet` keeps all per-client state *and* the per-OST
   cluster state as one jax pytree on a device across intervals, and
@@ -49,9 +48,15 @@ between shards are not counted.
 Ownership: whichever fleet last stepped owns the truth. Host-side
 reads go through :meth:`SoACore.ensure_host` (lazy pull); host-side
 state writes mark the device copy stale and the next device step
-re-uploads. jax stays a soft dependency — importing this module
-without jax installed raises the same actionable error as
-``backend="soa-jax"``.
+re-uploads. Both fleets take that hand-over, the statics upload and the
+state push/pull from one base (:class:`_HostPlumbing`), over a row
+index: all rows for :class:`DeviceFleet`, each shard's own for
+:class:`ShardedDeviceFleet`.
+
+jax stays a soft dependency: this is the storage layer's one module
+that imports it, and without jax installed importing it raises one
+actionable error. The model is float64 end to end, so importing this
+module, and building a fleet, turn ``jax_enable_x64`` on process-wide.
 
 The fused-step promise is lint-enforced: ``caratlint`` rule CL004
 flags host round-trips, Python control flow on traced values, and
@@ -66,11 +71,22 @@ import numpy as np
 
 from repro.core.runtime.telemetry.recorder import active as _telemetry
 from repro.storage.params import PAGE_SIZE, PFSParams
-from repro.storage.pfs import PFSCluster
-from repro.storage.soa import OP_FIELDS, SoACore, resolve_xp
+from repro.storage.pfs import OST_FIELDS, PFSCluster
+from repro.storage.soa import OP_FIELDS, SoACore
 
-jnp = resolve_xp("jax")          # actionable ImportError when jax is absent
-import jax                       # noqa: E402  (guarded by resolve_xp above)
+try:
+    import jax
+    import jax.numpy as jnp
+except ImportError as e:
+    raise ImportError(
+        "backend='soa-jax' requires jax, which is not installed. "
+        "Install the accelerator extra (pip install jax) or use "
+        "backend='soa' / backend='scalar', which are NumPy-only."
+    ) from e
+
+# the model is float64 end to end: without x64 every carried state
+# round-trip would truncate (each fleet turns it on again as it is built)
+jax.config.update("jax_enable_x64", True)
 
 _PAGE = float(PAGE_SIZE)
 
@@ -86,6 +102,8 @@ STATIC_FIELDS = (
 
 OST_STATE_FIELDS = ("ost_wait", "ost_util", "ost_inflight",
                     "ost_served_bytes", "ost_served_rpcs")
+
+_ALL_ROWS = slice(None)
 
 
 def _nbytes(tree) -> int:
@@ -108,6 +126,54 @@ def _onehot_T(n_osts: int, ch_ost) -> np.ndarray:
     per-step compare+convert from the segment reductions."""
     ids = np.asarray(ch_ost).ravel()
     return (np.arange(n_osts)[:, None] == ids[None, :]).astype(np.float64)
+
+
+# ---------------------------------------------------------------------------
+# host-side gather/scatter over a row index (a slice or an index array)
+# ---------------------------------------------------------------------------
+def _statics_at(core: SoACore, rows) -> Dict:
+    """The plan constants of clients ``rows`` plus their one-hot
+    channel->OST map (call after ``core._ensure_static``)."""
+    st = core._static
+    d = {f: getattr(st, f)[rows] for f in STATIC_FIELDS}
+    d["onehot_T"] = _onehot_T(core.p.n_osts, d["ch_ost"])
+    return d
+
+
+def _clients_at(core: SoACore, rows) -> Dict:
+    """The carried client state of clients ``rows``."""
+    return {
+        "dirty": core.dirty_bytes[rows], "last_drain": core.last_drain[rows],
+        "read": {f: getattr(core.read, f)[rows] for f in OP_FIELDS},
+        "write": {f: getattr(core.write, f)[rows] for f in OP_FIELDS},
+        "dirty_peak": core.dirty_peak_bytes[rows],
+        "inflight_peak": core.inflight_peak[rows],
+    }
+
+
+def _store_clients(core: SoACore, rows, h: Dict) -> None:
+    """Write host copy ``h`` of :func:`_clients_at` back to ``rows``."""
+    core.dirty_bytes[rows] = h["dirty"]
+    core.last_drain[rows] = h["last_drain"]
+    for f in OP_FIELDS:
+        getattr(core.read, f)[rows] = h["read"][f]
+        getattr(core.write, f)[rows] = h["write"][f]
+    core.dirty_peak_bytes[rows] = h["dirty_peak"]
+    core.inflight_peak[rows] = h["inflight_peak"]
+
+
+def _osts_of(cluster: PFSCluster) -> Dict:
+    """The cluster's per-OST state, keyed by ``OST_STATE_FIELDS``."""
+    return {f: getattr(cluster, a) for f, a in zip(OST_STATE_FIELDS,
+                                                   OST_FIELDS)}
+
+
+def _store_osts(core: SoACore, cluster: PFSCluster, h: Dict) -> None:
+    """Write host copy ``h`` of :func:`_osts_of` back to the cluster."""
+    for f, a in zip(OST_STATE_FIELDS, OST_FIELDS):
+        getattr(cluster, a)[:] = h[f]
+    # full-fleet contract: every client's waits row is the OST vector
+    core.waits[:, :] = h["ost_wait"][None, :]
 
 
 # ---------------------------------------------------------------------------
@@ -417,9 +483,82 @@ def _activity_mask(s: Dict, dirty, act):
 
 
 # ---------------------------------------------------------------------------
+# host plumbing shared by both fleets
+# ---------------------------------------------------------------------------
+class _HostPlumbing:
+    """Ownership of the core, the statics refresh, and the push/pull
+    bracketing both fleets share. ``_placement`` pairs each block of
+    client rows with the device that holds it; subclasses say what one
+    push uploads (``_upload``) and one pull writes back (``_download``).
+    """
+
+    def __init__(self, core: SoACore, cluster: PFSCluster,
+                 placement: List[tuple]):
+        jax.config.update("jax_enable_x64", True)   # see the module top
+        self.core = core
+        self.cluster = cluster
+        self._placement = placement
+        self.host_stale = False      # host arrays lag the device state
+        self.device_stale = True     # device copy lags the host arrays
+        self.n_traces = 0            # fused-step retrace count (tests)
+        self._statics: List[Dict] = []   # one per placement block
+        self._static_seen = -1
+        self._wl_seen = -1
+        self._mask: Optional[np.ndarray] = None
+
+    def _push(self) -> None:
+        """Upload host state to the device (host stays valid until the
+        next step marks it stale)."""
+        rec = _telemetry()
+        with rec.span("fleet.push", cat="fleet"):
+            pushed = self._upload()
+            if rec.enabled:
+                rec.count("fleet.h2d_bytes", _nbytes(pushed))
+        self.device_stale = False
+        self._mask = None            # dirty may have changed: recompute
+
+    def sync_host(self) -> None:
+        """Pull device state back into the core/cluster host arrays.
+        The device copy remains authoritative (reads don't invalidate)."""
+        rec = _telemetry()
+        with rec.span("fleet.sync_host", cat="fleet"):
+            pulled = self._download()
+            if rec.enabled:
+                rec.count("fleet.d2h_bytes", _nbytes(pulled))
+        self.host_stale = False
+
+    def _refresh_statics(self) -> None:
+        core = self.core
+        if _statics_current(core, self._static_seen):
+            return
+        rec = _telemetry()
+        with rec.span("fleet.statics", cat="fleet"):
+            core._ensure_static()
+            self._statics = [jax.device_put(_statics_at(core, rows), dev)
+                             for rows, dev in self._placement]
+            self._static_seen = core._static_version
+            if rec.enabled:
+                rec.count("fleet.h2d_bytes", _nbytes(self._statics))
+
+    def _take_ownership(self) -> None:
+        """Become the core's device owner (syncing any previous owner's
+        state through the host arrays first)."""
+        core = self.core
+        old = core._device
+        if old is self:
+            return
+        if old is not None:
+            if old.host_stale:
+                old.sync_host()
+            old.device_stale = True
+        core._device = self
+        self.device_stale = True
+
+
+# ---------------------------------------------------------------------------
 # single-device fused fleet
 # ---------------------------------------------------------------------------
-class DeviceFleet:
+class DeviceFleet(_HostPlumbing):
     """Device-resident full-fleet stepping for ``Simulation(backend="soa-jax")``.
 
     One fused, donated, jit-compiled ``step`` advances the whole fleet an
@@ -429,17 +568,9 @@ class DeviceFleet:
 
     def __init__(self, core: SoACore, cluster: PFSCluster,
                  device=None):
-        self.core = core
-        self.cluster = cluster
         self.device = device if device is not None else jax.devices()[0]
-        self.host_stale = False      # host arrays lag the device state
-        self.device_stale = True     # device copy lags the host arrays
-        self.n_traces = 0            # fused-step retrace count (tests)
+        super().__init__(core, cluster, [(_ALL_ROWS, self.device)])
         self._state = None
-        self._statics = None
-        self._static_seen = -1
-        self._wl_seen = -1
-        self._mask: Optional[np.ndarray] = None
         self._step_fn = self._build_step()
         self._act_fn = jax.jit(_duty_act)
         self._mask_fn = jax.jit(
@@ -479,87 +610,22 @@ class DeviceFleet:
 
     # ------------------------------------------------------- host <-> device
     def _host_state(self) -> Dict:
-        core, cl = self.core, self.cluster
         return {
-            "dirty": core.dirty_bytes, "last_drain": core.last_drain,
-            "read": {f: getattr(core.read, f) for f in OP_FIELDS},
-            "write": {f: getattr(core.write, f) for f in OP_FIELDS},
-            "dirty_peak": core.dirty_peak_bytes,
-            "inflight_peak": core.inflight_peak,
-            "ost_wait": cl.wait_s, "ost_util": cl.utilization,
-            "ost_inflight": cl.inflight,
-            "ost_served_bytes": cl.served_bytes,
-            "ost_served_rpcs": cl.served_rpcs,
+            **_clients_at(self.core, _ALL_ROWS), **_osts_of(self.cluster),
             # placeholder — step() recomputes it on every fresh push
             # (the push clears the predicted mask, forcing that branch)
-            "act": np.zeros(core.n, dtype=bool),
+            "act": np.zeros(self.core.n, dtype=bool),
         }
 
-    def _push(self) -> None:
-        """Upload host state to the device (host stays valid until the
-        next fused step marks it stale)."""
-        rec = _telemetry()
-        with rec.span("fleet.push", cat="fleet"):
-            self._state = jax.device_put(self._host_state(), self.device)
-            if rec.enabled:
-                rec.count("fleet.h2d_bytes", _nbytes(self._state))
-        self.device_stale = False
-        self._mask = None            # dirty may have changed: recompute
+    def _upload(self):
+        self._state = jax.device_put(self._host_state(), self.device)
+        return self._state
 
-    def _refresh_statics(self) -> None:
-        core = self.core
-        if _statics_current(core, self._static_seen):
-            return
-        rec = _telemetry()
-        with rec.span("fleet.statics", cat="fleet"):
-            core._ensure_static()
-            st = core._static
-            d = {f: np.asarray(getattr(st, f)) for f in STATIC_FIELDS}
-            d["onehot_T"] = _onehot_T(core.p.n_osts, st.ch_ost)
-            self._statics = jax.device_put(d, self.device)
-            self._static_seen = core._static_version
-            if rec.enabled:
-                rec.count("fleet.h2d_bytes", _nbytes(self._statics))
-
-    def sync_host(self) -> None:
-        """Pull device state back into the core/cluster host arrays.
-        The device copy remains authoritative (reads don't invalidate)."""
-        rec = _telemetry()
-        with rec.span("fleet.sync_host", cat="fleet"):
-            h = jax.tree.map(np.asarray, self._state)
-            core, cl = self.core, self.cluster
-            core.dirty_bytes[:] = h["dirty"]
-            core.last_drain[:] = h["last_drain"]
-            # full-fleet contract: every client's waits row is the OST
-            # vector
-            core.waits[:, :] = h["ost_wait"][None, :]
-            for f in OP_FIELDS:
-                getattr(core.read, f)[:] = h["read"][f]
-                getattr(core.write, f)[:] = h["write"][f]
-            core.dirty_peak_bytes[:] = h["dirty_peak"]
-            core.inflight_peak[:] = h["inflight_peak"]
-            cl.wait_s[:] = h["ost_wait"]
-            cl.utilization[:] = h["ost_util"]
-            cl.inflight[:] = h["ost_inflight"]
-            cl.served_bytes[:] = h["ost_served_bytes"]
-            cl.served_rpcs[:] = h["ost_served_rpcs"]
-            if rec.enabled:
-                rec.count("fleet.d2h_bytes", _nbytes(h))
-        self.host_stale = False
-
-    def _take_ownership(self) -> None:
-        """Become the core's device owner (syncing any previous owner's
-        state through the host arrays first)."""
-        core = self.core
-        old = core._device
-        if old is self:
-            return
-        if old is not None:
-            if old.host_stale:
-                old.sync_host()
-            old.device_stale = True
-        core._device = self
-        self.device_stale = True
+    def _download(self):
+        h = jax.tree.map(np.asarray, self._state)
+        _store_clients(self.core, _ALL_ROWS, h)
+        _store_osts(self.core, self.cluster, h)
+        return h
 
     # ----------------------------------------------------------------- step
     def step(self, t: float, dt: float):
@@ -573,24 +639,24 @@ class DeviceFleet:
             if self.device_stale or self._state is None:
                 self._push()
             self._refresh_statics()
+            statics = self._statics[0]           # one block: all rows
             if self._mask is None or self._wl_seen != core._wl_version:
                 # no valid predicted mask (fresh push or workload
                 # mutation): recompute this interval's duty activity + OST
                 # mask on-device (``act`` is computed there: its put is
                 # no transfer)
                 with rec.span("fleet.mask", cat="fleet"):
-                    act = self._act_fn(self._statics, t)
+                    act = self._act_fn(statics, t)
                     self._state["act"] = jax.device_put(act, self.device)
                     self._mask = np.asarray(
-                        self._mask_fn(self._state["dirty"], self._statics,
-                                      act))
+                        self._mask_fn(self._state["dirty"], statics, act))
                     self._wl_seen = core._wl_version
                 if rec.enabled:
                     rec.count("fleet.d2h_bytes", self._mask.nbytes)
             with rec.span("fleet.noise", cat="fleet"):
                 noise = self.cluster._noise_for(self._mask)
             state, totals, mask_next = self._step_fn(
-                self._state, self._statics, t, dt, noise)
+                self._state, statics, t, dt, noise)
             self._state = state
             self._mask = np.asarray(mask_next)
             self.host_stale = True
@@ -603,7 +669,7 @@ class DeviceFleet:
 # ---------------------------------------------------------------------------
 # shard -> device mapping (sync sharded runtime)
 # ---------------------------------------------------------------------------
-class ShardedDeviceFleet:
+class ShardedDeviceFleet(_HostPlumbing):
     """Map sharded-runtime shards onto devices.
 
     Each shard's client rows live on ``devices[i % len(devices)]``; a
@@ -619,22 +685,15 @@ class ShardedDeviceFleet:
     def __init__(self, core: SoACore, cluster: PFSCluster,
                  shard_idx: Sequence[np.ndarray],
                  devices: Optional[Sequence] = None):
-        self.core = core
-        self.cluster = cluster
         devs = list(devices) if devices is not None else jax.devices()
         self.shard_idx = [np.asarray(ix, dtype=np.int64) for ix in shard_idx]
         self.devices = [devs[i % len(devs)]
                         for i in range(len(self.shard_idx))]
         self.primary = devs[0]
-        self.host_stale = False
-        self.device_stale = True
-        self.n_traces = 0
+        super().__init__(core, cluster,
+                         list(zip(self.shard_idx, self.devices)))
         self._states: List[Dict] = []
-        self._statics: List[Dict] = []
         self._ost_state = None
-        self._static_seen = -1
-        self._wl_seen = -1
-        self._mask: Optional[np.ndarray] = None
         p = core.p
 
         def plan_fn(state, s, ost_wait, t, dt):
@@ -664,89 +723,20 @@ class ShardedDeviceFleet:
         self._lanes_fn = jax.jit(lanes_fn)
 
     # ------------------------------------------------------- host <-> device
-    def _push(self) -> None:
-        core, cl = self.core, self.cluster
-        rec = _telemetry()
-        with rec.span("fleet.push", cat="fleet"):
-            self._states = []
-            for ix, dev in zip(self.shard_idx, self.devices):
-                st = {
-                    "dirty": core.dirty_bytes[ix],
-                    "last_drain": core.last_drain[ix],
-                    "read": {f: getattr(core.read, f)[ix]
-                             for f in OP_FIELDS},
-                    "write": {f: getattr(core.write, f)[ix]
-                              for f in OP_FIELDS},
-                    "dirty_peak": core.dirty_peak_bytes[ix],
-                    "inflight_peak": core.inflight_peak[ix],
-                }
-                self._states.append(jax.device_put(st, dev))
-            self._ost_state = jax.device_put(
-                {"ost_wait": cl.wait_s, "ost_util": cl.utilization,
-                 "ost_inflight": cl.inflight,
-                 "ost_served_bytes": cl.served_bytes,
-                 "ost_served_rpcs": cl.served_rpcs}, self.primary)
-            if rec.enabled:
-                rec.count("fleet.h2d_bytes", _nbytes(self._states)
-                          + _nbytes(self._ost_state))
-        self.device_stale = False
-        self._mask = None
+    def _upload(self):
+        self._states = [jax.device_put(_clients_at(self.core, rows), dev)
+                        for rows, dev in self._placement]
+        self._ost_state = jax.device_put(_osts_of(self.cluster),
+                                         self.primary)
+        return self._states, self._ost_state
 
-    def _refresh_statics(self) -> None:
-        core = self.core
-        if _statics_current(core, self._static_seen):
-            return
-        rec = _telemetry()
-        with rec.span("fleet.statics", cat="fleet"):
-            core._ensure_static()
-            st = core._static
-            self._statics = []
-            for ix, dev in zip(self.shard_idx, self.devices):
-                sl = {f: np.asarray(getattr(st, f))[ix]
-                      for f in STATIC_FIELDS}
-                sl["onehot_T"] = _onehot_T(core.p.n_osts,
-                                           np.asarray(st.ch_ost)[ix])
-                self._statics.append(jax.device_put(sl, dev))
-            self._static_seen = core._static_version
-            if rec.enabled:
-                rec.count("fleet.h2d_bytes", _nbytes(self._statics))
-
-    def sync_host(self) -> None:
-        core, cl = self.core, self.cluster
-        rec = _telemetry()
-        with rec.span("fleet.sync_host", cat="fleet"):
-            for ix, st in zip(self.shard_idx, self._states):
-                h = jax.tree.map(np.asarray, st)
-                core.dirty_bytes[ix] = h["dirty"]
-                core.last_drain[ix] = h["last_drain"]
-                for f in OP_FIELDS:
-                    getattr(core.read, f)[ix] = h["read"][f]
-                    getattr(core.write, f)[ix] = h["write"][f]
-                core.dirty_peak_bytes[ix] = h["dirty_peak"]
-                core.inflight_peak[ix] = h["inflight_peak"]
-            ost = jax.tree.map(np.asarray, self._ost_state)
-            core.waits[:, :] = ost["ost_wait"][None, :]
-            cl.wait_s[:] = ost["ost_wait"]
-            cl.utilization[:] = ost["ost_util"]
-            cl.inflight[:] = ost["ost_inflight"]
-            cl.served_bytes[:] = ost["ost_served_bytes"]
-            cl.served_rpcs[:] = ost["ost_served_rpcs"]
-            if rec.enabled:
-                rec.count("fleet.d2h_bytes", _nbytes(self._states)
-                          + _nbytes(self._ost_state))
-        self.host_stale = False
-
-    def _take_ownership(self) -> None:
-        core = self.core
-        old = core._device
-        if old is self:
-            return
-        if old is not None:
-            if old.host_stale:
-                old.sync_host()
-            old.device_stale = True
-        core._device = self
-        self.device_stale = True
+    def _download(self):
+        states, ost = jax.tree.map(np.asarray,
+                                   (self._states, self._ost_state))
+        for (rows, _), h in zip(self._placement, states):
+            _store_clients(self.core, rows, h)
+        _store_osts(self.core, self.cluster, ost)
+        return states, ost
 
     # ----------------------------------------------------------------- step
     def step(self, t: float, dt: float) -> List:

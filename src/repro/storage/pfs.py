@@ -36,6 +36,16 @@ OST_FIELDS = ("wait_s", "utilization", "inflight", "served_bytes",
               "served_rpcs")
 
 
+def _left_sum(values) -> float:
+    """Sum in arrival order, one rounding per term: the fold order
+    ``_SegmentFold`` reproduces. (Python >= 3.12 compensates the float
+    builtin ``sum``, which is not that fold.)"""
+    total = 0.0
+    for v in values:
+        total += v
+    return total
+
+
 class OSTState:
     """Read/write view of one OST's row in the cluster state arrays."""
 
@@ -180,7 +190,7 @@ class PFSCluster:
             noise = float(self.rng.gen.lognormal(0.0, p.noise_sigma))
 
             # overload knee: concurrency past the knee inflates fixed cost
-            inflight_offered = sum(d.window for d in ds)
+            inflight_offered = _left_sum(d.window for d in ds)
             over = max(0.0, inflight_offered / p.ost_overload_knee - 1.0)
             fixed_eff = p.ost_fixed_cpu_s * (1.0 + p.ost_overload_gamma * over)
 
@@ -206,8 +216,8 @@ class PFSCluster:
 
             # queue delay feedback (served load rho after scaling)
             rho = min(util * scale, 0.95)
-            svc_avg = (sum(fixed_eff + d.rpc_pages * PAGE_SIZE / disk_bw
-                           for d in ds) / len(ds))
+            svc_avg = (_left_sum(fixed_eff + d.rpc_pages * PAGE_SIZE / disk_bw
+                                 for d in ds) / len(ds))
             wait_now = min(p.queue_wait_cap_s, svc_avg * rho / max(1 - rho, 0.05))
             if util > 1.0:   # saturated: queue rides the cap
                 wait_now = p.queue_wait_cap_s
@@ -216,7 +226,8 @@ class PFSCluster:
             self.utilization[ost_id] = util
             self.inflight[ost_id] = inflight_offered
             self.served_bytes[ost_id] += byte_rate * scale * dt
-            self.served_rpcs[ost_id] += sum(d.rpc_rate for d in ds) * scale * dt
+            self.served_rpcs[ost_id] += (_left_sum(d.rpc_rate for d in ds)
+                                         * scale * dt)
 
             scale_arr[ost_id] = scale
         return ClusterFeedback(scale_arr, self.wait_s.copy())

@@ -258,14 +258,14 @@ class Simulation:
             # (per-client rng forks are skipped: IOClient never draws from
             # its stream, and RngStream.fork is hash-derived — it consumes
             # nothing from the parent, so the cluster stream is unaffected)
-            self.core = SoACore(
-                self.p, list(workloads), own_cfgs, ids, offsets,
-                xp=("jax" if backend == "soa-jax" else "numpy"))
+            self.core = SoACore(self.p, list(workloads), own_cfgs, ids,
+                                offsets)
             self.clients = [SoAClientView(self.core, i)
                             for i in range(len(ids))]
         # soa-jax: fleet state lives on-device across intervals, stepped
-        # by one fused jit (storage.device). Host-side phase methods stay
-        # available (ShardedRuntime) — SoACore's ensure_host/host_mutated
+        # by one fused jit (storage.device; building it turns jax x64 on).
+        # Host-side phase methods stay available (ShardedRuntime) and run
+        # the same NumPy core as "soa" — SoACore's ensure_host/host_mutated
         # hooks keep the two sides coherent.
         self.device_fleet = None
         if backend == "soa-jax":

@@ -35,13 +35,12 @@ The float-order contract is lint-enforced: ``caratlint`` rule CL003
 flags reassociating reductions and unstable sorts in this module (see
 ``CONTRIBUTING.md`` for the rule catalogue and suppression syntax).
 
-Backends: ``xp="numpy"`` (default) or ``xp="jax"`` — the elementwise
-plan/commit math runs through the array namespace while carried state
-stays NumPy (the cluster RNG is NumPy either way). The jax backend
-enables x64 and is *tolerance*-checked against numpy, not
-identity-gated: XLA may fuse/reassociate elementwise chains. With
-``XLA_FLAGS=--xla_force_host_platform_device_count=N`` it runs on a
-multi-device CPU mesh (see ``tests/test_soa.py``).
+The core is NumPy only, for every backend. ``Simulation(backend=
+"soa-jax")`` builds this same core and steps whole intervals on the
+device through :class:`~repro.storage.device.DeviceFleet`, which keeps
+the fleet's state there and hands it back through
+:meth:`SoACore.ensure_host`; the host phases (sharded runtimes, process
+workers) run the code below and stay bit-identical to ``"soa"``.
 """
 from __future__ import annotations
 
@@ -66,36 +65,6 @@ OP_FIELDS = ("app_bytes", "app_requests", "rpc_count", "rpc_pages",
              "absorbed_bytes", "blocked_s", "active_s")
 
 _PAGE = float(PAGE_SIZE)
-
-
-def resolve_xp(backend: str):
-    """Array namespace for ``backend`` ("numpy" | "jax").
-
-    jax is a *soft* dependency of the storage layer: the scalar and
-    ``soa`` backends never import it, and asking for the jax backend
-    without jax installed raises one actionable error instead of a bare
-    ``ModuleNotFoundError`` from deep inside a plan call.
-    """
-    if backend == "numpy":
-        return np
-    if backend == "jax":
-        try:
-            import jax
-        except ImportError as e:
-            raise ImportError(
-                "backend='soa-jax' requires jax, which is not installed. "
-                "Install the accelerator extra (pip install jax) or use "
-                "backend='soa' / backend='scalar', which are NumPy-only."
-            ) from e
-
-        # the model is float64 end to end; without x64 every carried
-        # state round-trip would truncate
-        jax.config.update("jax_enable_x64", True)
-        import jax.numpy as jnp
-
-        return jnp
-    raise ValueError(f"unknown array backend {backend!r}; "
-                     f"expected 'numpy' or 'jax'")
 
 
 class OpArrays:
@@ -240,7 +209,6 @@ class SoACore:
         configs: Sequence[ClientConfig],
         client_ids: Sequence[int],
         stripe_offsets: Sequence[int],
-        xp: str = "numpy",
     ):
         n = len(workloads)
         if not (len(configs) == len(client_ids) == len(stripe_offsets) == n):
@@ -248,8 +216,6 @@ class SoACore:
                              "must be position-aligned")
         self.p = params
         self.n = n
-        self.backend = xp
-        self.xp = resolve_xp(xp)
         self.client_ids = np.asarray(list(client_ids), dtype=np.int64)
         self.stripe_offset = np.asarray(list(stripe_offsets), dtype=np.int64)
 
@@ -488,7 +454,6 @@ class SoACore:
         self.ensure_host()
         self._ensure_static()
         s = self._static
-        xp = self.xp
         p = self.p
         idx = np.asarray(idx, dtype=np.int64)
         full = idx is self.idx_all
@@ -498,7 +463,7 @@ class SoACore:
 
         ch_ost = G(s.ch_ost)
         ch_valid = G(s.ch_valid)
-        dirty_np = G(self.dirty_bytes)
+        dirty = G(self.dirty_bytes)
 
         # WorkloadSpec.active(t): idle (duty<=0) never; duty>=1 always;
         # else (t % period) < duty * period
@@ -507,64 +472,60 @@ class SoACore:
 
         is_read = G(s.is_read)
         is_mixed = G(s.is_mixed)
-        planned = act | (dirty_np > 0.0)
-        has_write = planned & (~is_read | (dirty_np > 0.0))
-        drain_only = planned & is_read & (dirty_np > 0.0)
+        planned = act | (dirty > 0.0)
+        has_write = planned & (~is_read | (dirty > 0.0))
+        drain_only = planned & is_read & (dirty > 0.0)
         has_read = planned & act & (is_read | is_mixed)
         # the `active` argument to _plan_write governs the app offer; the
         # drain-only path passes active=False regardless of wl.active(t)
         w_stream_active = act & ~is_read
 
-        # ---- xp conversions (no-ops for numpy) -----------------------------
-        A = xp.asarray
-        dirty = A(dirty_np)
-        Wf = A(G(s.W))
-        Ff = A(G(s.F))
-        R = A(G(s.R))
-        last_drain = A(G(self.last_drain))
-        n_ch_f = A(G(s.n_ch_f))
-        nic_per_ch = A(G(s.nic_per_ch))
-        wait_ch = A(np.take_along_axis(G(self.waits), ch_ost, axis=1))
+        Wf = G(s.W)
+        Ff = G(s.F)
+        R = G(s.R)
+        last_drain = G(self.last_drain)
+        n_ch_f = G(s.n_ch_f)
+        nic_per_ch = G(s.nic_per_ch)
+        wait_ch = np.take_along_axis(G(self.waits), ch_ost, axis=1)
 
         # ================= write plan (_plan_write) =========================
-        lam_req = xp.where(A(w_stream_active), A(G(s.lam_rate_w)), 0.0)
+        lam_req = np.where(w_stream_active, G(s.lam_rate_w), 0.0)
         lam_bytes_w = lam_req * R
 
-        absorb_frac = A(G(s.inplace)) * xp.minimum(1.0,
-                                                   dirty / A(G(s.hot_bytes)))
+        absorb_frac = G(s.inplace) * np.minimum(1.0, dirty / G(s.hot_bytes))
 
         # random-access extent fill (the only dynamic p_eff branch)
-        lam_pages = xp.maximum(last_drain, lam_bytes_w * 0.25) / PAGE_SIZE
-        density = (lam_pages * p.extent_timeout_s) / A(G(s.n_extents))
-        p_eff_random = xp.minimum(Wf, xp.maximum(A(G(s.run)), density))
-        seq_like = A(drain_only) | A(G(s.is_seq))
-        p_eff = xp.where(seq_like, Wf,
-                         xp.where(A(G(s.is_strided)), A(G(s.p_eff_strided)),
+        lam_pages = np.maximum(last_drain, lam_bytes_w * 0.25) / PAGE_SIZE
+        density = (lam_pages * p.extent_timeout_s) / G(s.n_extents)
+        p_eff_random = np.minimum(Wf, np.maximum(G(s.run), density))
+        seq_like = drain_only | G(s.is_seq)
+        p_eff = np.where(seq_like, Wf,
+                         np.where(G(s.is_strided), G(s.p_eff_strided),
                                   p_eff_random))
         fill_frac = p_eff / Wf
 
         # new_dirty_est = max(last_drain, lam_bytes * (1 - absorb) * 0.25)
-        new_dirty_est = xp.maximum(last_drain,
+        new_dirty_est = np.maximum(last_drain,
                                    (lam_bytes_w * (1.0 - absorb_frac)) * 0.25)
         # shared sub-expression of open_extents and timeout_occ:
         # new_dirty_est * extent_timeout_s * (1.0 - fill_frac)
         parked = (new_dirty_est * p.extent_timeout_s) * (1.0 - fill_frac)
-        open_extents = parked / xp.maximum(p_eff * PAGE_SIZE, 1.0)
+        open_extents = parked / np.maximum(p_eff * PAGE_SIZE, 1.0)
         frag_commit = ((open_extents * Wf) * _PAGE) * p.frag_overhead
-        C = A(G(s.C))
-        c_eff = xp.maximum(C - frag_commit, 0.1 * C)
-        timeout_occ = xp.minimum(parked, 0.8 * c_eff)
-        headroom = xp.maximum((c_eff - dirty) - timeout_occ, 0.0)
+        C = G(s.C)
+        c_eff = np.maximum(C - frag_commit, 0.1 * C)
+        timeout_occ = np.minimum(parked, 0.8 * c_eff)
+        headroom = np.maximum((c_eff - dirty) - timeout_occ, 0.0)
 
         admit_cap = ((last_drain + headroom / dt)
-                     / xp.maximum(1.0 - absorb_frac, 1e-3))
+                     / np.maximum(1.0 - absorb_frac, 1e-3))
         admit_floor = (0.05 * c_eff) / dt
-        admitted = xp.minimum(lam_bytes_w, xp.maximum(admit_cap, admit_floor))
+        admitted = np.minimum(lam_bytes_w, np.maximum(admit_cap, admit_floor))
         absorbed = admitted * absorb_frac
         new_dirty_rate = admitted - absorbed
 
         rpc_bytes_w = p_eff * PAGE_SIZE
-        form_cost = (1.0 - fill_frac) * A(G(s.form_scan)) + 30e-6
+        form_cost = (1.0 - fill_frac) * G(s.form_scan) + 30e-6
         form_bytes_cap = rpc_bytes_w / form_cost
 
         total_backlog = dirty / dt + new_dirty_rate
@@ -576,51 +537,47 @@ class SoACore:
                    + rb_w / p.ost_disk_bw) + rb_w / p.nic_bw
         window_cap = (Ff[:, None] * rb_w) / t_rpc_w
         # offer = min(per_ch_backlog, window_cap, nic_cap, form_cap/n_ch)
-        offer = xp.minimum(
-            xp.minimum(xp.minimum(per_ch_backlog[:, None], window_cap),
+        offer = np.minimum(
+            np.minimum(np.minimum(per_ch_backlog[:, None], window_cap),
                        nic_per_ch[:, None]),
             (form_bytes_cap / n_ch_f)[:, None])
         w_rate = offer / rb_w
-        w_window = xp.minimum(Ff[:, None], (offer * t_rpc_w) / rb_w + 0.01)
+        w_window = np.minimum(Ff[:, None], (offer * t_rpc_w) / rb_w + 0.01)
 
         # ================= read plan (_plan_read) ===========================
         # --- seq/strided: readahead pipeline --------------------------------
-        rb_sl = A(G(s.rb_sl))[:, None]
+        rb_sl = G(s.rb_sl)[:, None]
         t_rpc_sl = (((p.net_rtt_s + wait_ch) + p.ost_fixed_cpu_s)
                     + rb_sl / p.ost_disk_bw) + rb_sl / p.nic_bw
-        depth = A(G(s.depth))
-        cap_sl = xp.minimum(
-            xp.minimum((depth * rb_sl) / t_rpc_sl, nic_per_ch[:, None]),
-            A(G(s.lam_r_per_ch))[:, None])
+        depth = G(s.depth)
+        cap_sl = np.minimum(
+            np.minimum((depth * rb_sl) / t_rpc_sl, nic_per_ch[:, None]),
+            G(s.lam_r_per_ch)[:, None])
         rate_sl = cap_sl / rb_sl
-        win_sl = xp.minimum(depth, (cap_sl * t_rpc_sl) / rb_sl + 0.01)
+        win_sl = np.minimum(depth, (cap_sl * t_rpc_sl) / rb_sl + 0.01)
 
         # --- random: latency-bound requests ---------------------------------
-        rb_rd = A(G(s.rb_rd))[:, None]
+        rb_rd = G(s.rb_rd)[:, None]
         t_rpc_rd = (((p.net_rtt_s + wait_ch) + p.ost_fixed_cpu_s)
                     + rb_rd / p.ost_disk_bw) + rb_rd / p.nic_bw
         # t_req = t_rpc*waves + misfire + syscall + think
-        t_req = ((t_rpc_rd * A(G(s.waves))[:, None]
-                  + A(G(s.misfire))[:, None])
-                 + p.syscall_s) + A(G(s.think))[:, None]
-        cap_rd = xp.minimum((A(G(s.s_here)) * R[:, None]) / t_req,
+        t_req = ((t_rpc_rd * G(s.waves)[:, None] + G(s.misfire)[:, None])
+                 + p.syscall_s) + G(s.think)[:, None]
+        cap_rd = np.minimum((G(s.s_here) * R[:, None]) / t_req,
                             nic_per_ch[:, None])
         rate_rd = cap_rd / rb_rd
 
-        is_rand2 = A(G(s.is_rand))[:, None]
-        r_rate = xp.where(is_rand2, rate_rd, rate_sl)
-        r_window = xp.where(is_rand2, A(G(s.win_rd)), win_sl)
-
-        asnp = np.asarray
+        is_rand2 = G(s.is_rand)[:, None]
         return PlanBatch(
             idx=idx, t=t, dt=dt, active=act,
             has_write=has_write, has_read=has_read,
             ch_ost=ch_ost, ch_valid=ch_valid,
-            w_pages=asnp(p_eff), w_rate=asnp(w_rate), w_window=asnp(w_window),
-            admitted=asnp(admitted), absorbed=asnp(absorbed),
-            new_dirty_rate=asnp(new_dirty_rate), lam_bytes=asnp(lam_bytes_w),
-            r_pages=G(s.r_pages), r_rate=asnp(r_rate),
-            r_window=asnp(r_window))
+            w_pages=p_eff, w_rate=w_rate, w_window=w_window,
+            admitted=admitted, absorbed=absorbed,
+            new_dirty_rate=new_dirty_rate, lam_bytes=lam_bytes_w,
+            r_pages=G(s.r_pages),
+            r_rate=np.where(is_rand2, rate_rd, rate_sl),
+            r_window=np.where(is_rand2, G(s.win_rd), win_sl))
 
     # ------------------------------------------------------------ committing
     def commit(self, pb: PlanBatch, scale_arr: np.ndarray,
@@ -635,7 +592,6 @@ class SoACore:
         self.host_mutated()
         self._ensure_static()
         s = self._static
-        xp = self.xp
         p = self.p
         idx = pb.idx
         full = idx is self.idx_all
@@ -653,28 +609,25 @@ class SoACore:
         def G(a):
             return a if full else a[idx]
 
-        A = xp.asarray
-        scale_ch = A(scale_arr[ch_ost])
-        wait_ch = A(waits_arr[ch_ost])
+        scale_ch = scale_arr[ch_ost]
+        wait_ch = waits_arr[ch_ost]
         valid = pb.ch_valid
-        valid_x = A(valid)
-        hw_np = pb.has_write
-        hr_np = pb.has_read
-        dirty_np = self.dirty_bytes.copy() if full else self.dirty_bytes[idx]
-        dirty = A(dirty_np)
-        req_g = A(G(s.req_g))
-        cache = A(G(s.C))
-        zero = xp.zeros(idx.shape[0])
+        hw = pb.has_write
+        hr = pb.has_read
+        dirty = G(self.dirty_bytes)
+        req_g = G(s.req_g)
+        cache = G(s.C)
+        zero = np.zeros(idx.shape[0])
 
-        def channel_sums(rate_np, pages_1d):
+        def channel_sums(rate, pages_1d):
             """Sequential per-client channel sums (scalar demand order):
             masked lanes contribute exact +0.0 terms."""
             rb = pages_1d * PAGE_SIZE
             rb2 = rb[:, None]
             t_rpc = (((p.net_rtt_s + wait_ch) + p.ost_fixed_cpu_s)
                      + rb2 / p.ost_disk_bw) + rb2 / p.nic_bw
-            ach = xp.where(valid_x, A(rate_np) * scale_ch, 0.0)
-            trm = xp.where(valid_x, t_rpc, 0.0)
+            ach = np.where(valid, rate * scale_ch, 0.0)
+            trm = np.where(valid, t_rpc, 0.0)
             byte_sum = zero
             inflight = zero
             lat_sum = zero
@@ -690,13 +643,11 @@ class SoACore:
                 pages_sum = pages_sum + (a * dt) * pages_1d
             # channel_time counts live channels: integer, order-free
             # caratlint: disable=CL003 (bool-mask count, not a float fold)
-            n_live = (valid & (rate_np > 0.0)).sum(axis=1).astype(np.float64)
+            n_live = (valid & (rate > 0.0)).sum(axis=1).astype(np.float64)
             return byte_sum, inflight, lat_sum, rpcs, pages_sum, n_live
 
-        asnp = np.asarray
-
-        def bump(arr: np.ndarray, mask_np, values) -> None:
-            contrib = np.where(mask_np, asnp(values), 0.0)
+        def bump(arr: np.ndarray, mask, values) -> None:
+            contrib = np.where(mask, values, 0.0)
             if full:
                 arr += contrib
             else:
@@ -709,75 +660,68 @@ class SoACore:
                 arr[idx] = values
 
         # ================= write commit (_commit_write) =====================
-        w_pages = A(pb.w_pages)
         (drained, inflight_w, lat_w, rpcs_w, _,
-         live_w) = channel_sums(pb.w_rate, w_pages)
-        drained = xp.minimum(drained, dirty / dt + A(pb.new_dirty_rate))
+         live_w) = channel_sums(pb.w_rate, pb.w_pages)
+        drained = np.minimum(drained, dirty / dt + pb.new_dirty_rate)
 
-        admitted = A(pb.admitted)
-        absorbed = A(pb.absorbed)
+        admitted = pb.admitted
+        absorbed = pb.absorbed
         delta = ((admitted - absorbed) - drained) * dt
         new_dirty = dirty + delta
         over = new_dirty > cache
         overflow = new_dirty - cache
-        af2 = absorbed / xp.maximum(admitted, 1e-9)
-        shrink = xp.minimum(overflow / xp.maximum(1.0 - af2, 1e-3),
+        af2 = absorbed / np.maximum(admitted, 1e-9)
+        shrink = np.minimum(overflow / np.maximum(1.0 - af2, 1e-3),
                             admitted * dt)
-        adm2 = xp.maximum(admitted - shrink / dt, 0.0)
+        adm2 = np.maximum(admitted - shrink / dt, 0.0)
         abs2 = adm2 * af2
-        nd2 = xp.minimum(dirty + ((adm2 - abs2) - drained) * dt, cache)
-        blk2 = xp.minimum(dt, overflow / xp.maximum(A(pb.lam_bytes), 1.0))
-        admitted = xp.where(over, adm2, admitted)
-        absorbed = xp.where(over, abs2, absorbed)
-        new_dirty = xp.where(over, nd2, new_dirty)
-        blocked = xp.where(over, blk2, 0.0)
-        new_dirty = xp.maximum(new_dirty, 0.0)
+        nd2 = np.minimum(dirty + ((adm2 - abs2) - drained) * dt, cache)
+        blk2 = np.minimum(dt, overflow / np.maximum(pb.lam_bytes, 1.0))
+        admitted = np.where(over, adm2, admitted)
+        absorbed = np.where(over, abs2, absorbed)
+        new_dirty = np.where(over, nd2, new_dirty)
+        blocked = np.where(over, blk2, 0.0)
+        new_dirty = np.maximum(new_dirty, 0.0)
 
-        store(self.dirty_bytes, np.where(hw_np, asnp(new_dirty), dirty_np))
-        store(self.last_drain,
-              np.where(hw_np, asnp(drained),
-                       self.last_drain if full else self.last_drain[idx]))
+        store(self.dirty_bytes, np.where(hw, new_dirty, dirty))
+        store(self.last_drain, np.where(hw, drained, G(self.last_drain)))
 
         st = self.write
-        bump(st.app_bytes, hw_np, admitted * dt)
-        bump(st.app_requests, hw_np, (admitted * dt) / req_g)
-        bump(st.rpc_count, hw_np, rpcs_w)
-        bump(st.rpc_pages, hw_np, (drained * dt) / PAGE_SIZE)
-        bump(st.rpc_bytes, hw_np, drained * dt)
-        bump(st.lat_sum_s, hw_np, lat_w)
-        bump(st.inflight_time, hw_np, inflight_w * dt)
-        bump(st.channel_time, hw_np, live_w * dt)
-        bump(st.absorbed_bytes, hw_np, absorbed * dt)
-        bump(st.blocked_s, hw_np, blocked)
-        bump(st.active_s, hw_np & pb.active, dt)
-        ip = self.inflight_peak if full else self.inflight_peak[idx]
-        store(self.inflight_peak,
-              np.where(hw_np, np.maximum(ip, asnp(inflight_w)), ip))
+        bump(st.app_bytes, hw, admitted * dt)
+        bump(st.app_requests, hw, (admitted * dt) / req_g)
+        bump(st.rpc_count, hw, rpcs_w)
+        bump(st.rpc_pages, hw, (drained * dt) / PAGE_SIZE)
+        bump(st.rpc_bytes, hw, drained * dt)
+        bump(st.lat_sum_s, hw, lat_w)
+        bump(st.inflight_time, hw, inflight_w * dt)
+        bump(st.channel_time, hw, live_w * dt)
+        bump(st.absorbed_bytes, hw, absorbed * dt)
+        bump(st.blocked_s, hw, blocked)
+        bump(st.active_s, hw & pb.active, dt)
+        ip = G(self.inflight_peak)
+        store(self.inflight_peak, np.where(hw, np.maximum(ip, inflight_w), ip))
 
         # ================= read commit (_commit_read) =======================
-        r_pages = A(pb.r_pages)
         (delivered, inflight_r, lat_r, rpcs_r, pages_r,
-         live_r) = channel_sums(pb.r_rate, r_pages)
+         live_r) = channel_sums(pb.r_rate, pb.r_pages)
         st = self.read
-        bump(st.app_bytes, hr_np, delivered * dt)
-        bump(st.app_requests, hr_np, (delivered * dt) / req_g)
-        bump(st.rpc_count, hr_np, rpcs_r)
-        bump(st.rpc_pages, hr_np, pages_r)
-        bump(st.rpc_bytes, hr_np, delivered * dt)
-        bump(st.lat_sum_s, hr_np, lat_r)
-        bump(st.inflight_time, hr_np, inflight_r * dt)
-        bump(st.channel_time, hr_np, live_r * dt)
+        bump(st.app_bytes, hr, delivered * dt)
+        bump(st.app_requests, hr, (delivered * dt) / req_g)
+        bump(st.rpc_count, hr, rpcs_r)
+        bump(st.rpc_pages, hr, pages_r)
+        bump(st.rpc_bytes, hr, delivered * dt)
+        bump(st.lat_sum_s, hr, lat_r)
+        bump(st.inflight_time, hr, inflight_r * dt)
+        bump(st.channel_time, hr, live_r * dt)
         # has_read requires the active phase, so active_s needs no extra
-        # plan.active conjunct (hr_np implies pb.active)
-        bump(st.active_s, hr_np, dt)
-        ip = self.inflight_peak if full else self.inflight_peak[idx]
-        store(self.inflight_peak,
-              np.where(hr_np, np.maximum(ip, asnp(inflight_r)), ip))
+        # plan.active conjunct (hr implies pb.active)
+        bump(st.active_s, hr, dt)
+        ip = G(self.inflight_peak)
+        store(self.inflight_peak, np.where(hr, np.maximum(ip, inflight_r), ip))
 
         # ---- gauges (every committed client, like the scalar epilogue) -----
-        dp = self.dirty_peak_bytes if full else self.dirty_peak_bytes[idx]
-        db = self.dirty_bytes if full else self.dirty_bytes[idx]
-        store(self.dirty_peak_bytes, np.maximum(dp, db))
+        store(self.dirty_peak_bytes,
+              np.maximum(G(self.dirty_peak_bytes), G(self.dirty_bytes)))
 
     # ------------------------------------------------------------- snapshots
     def materialize_stats(self, i: int) -> ClientStats:

@@ -212,7 +212,9 @@ def test_carat_policy_decision_identical():
 # ---------------------------------------------------------------- sharded
 def test_sharded_sync_soa_identical():
     """Sync sharded execution over SoA slices reassembles the canonical
-    demand order: identical to single-process SoA *and* sharded scalar."""
+    demand order: identical to single-process SoA *and* sharded scalar,
+    and a ``soa-jax`` fleet's host phases (no ``device_map``) run the
+    same NumPy core, so they are identical too."""
     wls = [get_workload(NAMES[i % len(NAMES)]) for i in range(12)]
     topo = [f"node{i // 2}" for i in range(12)]
     kw = dict(params=PFSParams(n_osts=6), seed=7, topology=topo)
@@ -230,6 +232,13 @@ def test_sharded_sync_soa_identical():
     sc_res = ShardedRuntime(sc, mode="sync", n_shards=3).run(10.0)
     _assert_identical(sc, sh, "sharded-scalar-vs-soa")
     assert sc_res.client_throughput == sh_res.client_throughput
+
+    sj = Simulation(wls, backend="soa-jax", **kw)
+    sj_res = ShardedRuntime(sj, mode="sync", n_shards=3).run(10.0)
+    _assert_identical(ref, sj, "sharded-soa-jax-vs-single-soa")
+    assert ref_res.client_throughput == sj_res.client_throughput
+    assert ref_res.app_read_bytes == sj_res.app_read_bytes
+    assert ref_res.app_write_bytes == sj_res.app_write_bytes
 
 
 def test_sharded_async_soa_runs():
@@ -271,11 +280,12 @@ def test_config_validation_mirrors_scalar():
         c.set_cache_limit(0)
 
 
-# ------------------------------------------------------------- jnp backend
+# ------------------------------------------------------ device fleet
 def test_jax_backend_matches_numpy_within_tolerance():
-    """The jnp backend shares the state layout but not the exact kernel
-    fusion, so it is tolerance-gated (documented float-reassociation
-    point), not bit-gated."""
+    """``soa-jax`` steps through ``DeviceFleet``, whose fused step sums
+    per OST and per client in another order than the NumPy core, so it
+    is held to ``soa`` within tolerance (the documented float-
+    reassociation point), not bit for bit."""
     wls = [get_workload(NAMES[i % len(NAMES)]) for i in range(6)]
     res = {}
     for b in ("soa", "soa-jax"):
@@ -289,8 +299,9 @@ def test_jax_backend_matches_numpy_within_tolerance():
 
 @pytest.mark.slow
 def test_jax_backend_multi_device_subprocess():
-    """SNIPPETS-style forced host devices: the jnp backend must work when
-    XLA exposes 8 CPU devices (flags must not leak into this process)."""
+    """SNIPPETS-style forced host devices: ``DeviceFleet`` must hold to
+    NumPy within tolerance when XLA exposes 8 CPU devices (flags must
+    not leak into this process)."""
     script = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"

@@ -100,15 +100,13 @@ def test_fused_fleet_step_compiles_for_v5e(one_chip):
     striped mix, widened from a 16-client fleet's pytrees to 4,096
     clients as shapes only."""
     from repro.storage import Simulation
-    from repro.storage.device import STATIC_FIELDS, _onehot_T
+    from repro.storage.device import _statics_at
     from repro.storage.workloads import striped_fleet
     n0, n = 16, 4096
     sim = Simulation(striped_fleet(n0), seed=1, backend="soa-jax")
     core, fleet = sim.core, sim.device_fleet
     core._ensure_static()
-    statics = {f: np.asarray(getattr(core._static, f))
-               for f in STATIC_FIELDS}
-    statics["onehot_T"] = _onehot_T(core.p.n_osts, core._static.ch_ost)
+    statics = _statics_at(core, slice(None))
     kmax = statics["ch_ost"].shape[1]
     assert len({n0, n0 * kmax, core.p.n_osts}) == 3   # dims stay distinct
 

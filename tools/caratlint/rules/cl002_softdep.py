@@ -14,9 +14,11 @@ package of an imported module, so ``a.b.c`` also edges to ``a`` and
 through ``pkg/sub/__init__.py``.
 
 Fix a finding by deferring the import into the function that needs it
-(see ``resolve_xp`` in ``repro/storage/soa.py``) or, for a module that
-is *supposed* to need jax, adding it to ``cl002_allowed`` in the lint
-config — an explicit, reviewed exemption.
+(as ``Simulation.__init__`` in ``repro/storage/sim.py`` imports
+``repro.storage.device``, the storage layer's one jax module, only for
+``backend="soa-jax"``) or, for a module that is *supposed* to need jax,
+adding it to ``cl002_allowed`` in the lint config — an explicit,
+reviewed exemption.
 """
 from __future__ import annotations
 
